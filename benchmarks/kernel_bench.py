@@ -16,6 +16,7 @@ import numpy as np
 from benchmarks.common import smoke_clamp
 from repro.configs import get_reduced
 from repro.kernels import ops, ref
+from repro.kvcache.paged import gather_slots
 from repro.models import layers as L
 from repro.models import model as M
 
@@ -35,7 +36,7 @@ def _paged_decode_rows() -> list:
     n_blocks = B * nb_req + 1                       # block 0 = scratch
     S = nb_req * bs
     key = jax.random.PRNGKey(1)
-    kp = jax.random.normal(key, (cfg.n_layers, n_blocks, bs, cfg.n_kv_heads,
+    kp = jax.random.normal(key, (cfg.n_layers, n_blocks, cfg.n_kv_heads, bs,
                                  cfg.hd))
     vp = kp * 0.5
     toks = jnp.ones((B, 1), jnp.int32)
@@ -52,8 +53,8 @@ def _paged_decode_rows() -> list:
     wslot = np.full((B,), (ctx - 1) % bs, np.int32)
 
     def dense_step(params, toks, blk_map, slot_map, lengths, kp, vp):
-        k = kp[:, blk_map, slot_map]                # (L, B, S, KV, hd)
-        v = vp[:, blk_map, slot_map]
+        k = gather_slots(kp, blk_map, slot_map)     # (L, B, S, KV, hd)
+        v = gather_slots(vp, blk_map, slot_map)
         logits, _ = M.decode_step(cfg, params, toks, {"k": k, "v": v},
                                   lengths + 1)
         return jnp.argmax(logits[:, -1], axis=-1)
@@ -62,7 +63,7 @@ def _paged_decode_rows() -> list:
                    kp, vp):
         logits, kp, vp = M.paged_decode_step(
             cfg, params, toks, kp, vp, tables, counts, starts, wblk, wslot,
-            pos, attn_impl="jnp")
+            pos)
         return jnp.argmax(logits[:, -1], axis=-1), kp, vp
 
     dense = jax.jit(dense_step)
@@ -122,7 +123,7 @@ def _paged_prefill_rows() -> list:
     nb_req = -(-total // bs)
     n_blocks = B * nb_req + 1                       # block 0 = scratch
     key = jax.random.PRNGKey(3)
-    kp = jax.random.normal(key, (cfg.n_layers, n_blocks, bs, cfg.n_kv_heads,
+    kp = jax.random.normal(key, (cfg.n_layers, n_blocks, cfg.n_kv_heads, bs,
                                  cfg.hd), cfg.jdtype)
     vp = kp * 0.5
     rng = np.random.default_rng(0)
@@ -140,7 +141,8 @@ def _paged_prefill_rows() -> list:
                        (B, 1))[:, :pref]
 
     def dense_one(params, toks_b, blk_b, slot_b, kp, vp):
-        pc = {"k": kp[:, blk_b, slot_b], "v": vp[:, blk_b, slot_b]}
+        pc = {"k": gather_slots(kp, blk_b, slot_b),
+              "v": gather_slots(vp, blk_b, slot_b)}
         logits, _ = M.prefill(cfg, params, {"tokens": toks_b},
                               prefix_cache=pc, prefix_len=pref)
         return jnp.argmax(logits[:, -1], axis=-1)
@@ -149,7 +151,7 @@ def _paged_prefill_rows() -> list:
                    kp, vp):
         logits, kp, vp = M.paged_prefill_step(
             cfg, params, toks, kp, vp, tables, counts, starts, qs, ql,
-            wblk, wslot, attn_impl="jnp")
+            wblk, wslot)
         return jnp.argmax(logits[:, 0], axis=-1), kp, vp
 
     dense = jax.jit(dense_one)

@@ -1,8 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On a TPU runtime these dispatch to the compiled kernels; on CPU (this
-container) they run in interpret mode, which executes the kernel body in
-Python and validates the BlockSpec/grid logic bit-for-bit.
+On a TPU runtime these dispatch to the compiled kernels; on CPU they run in
+interpret mode (or, for the paged kernels, the pure-jnp twin), which
+executes the kernel logic off the chip.  On a TPU the paged dispatch never
+takes the jnp twin: a serving step there either runs the compiled kernel or
+fails.
 """
 from __future__ import annotations
 
@@ -18,6 +20,20 @@ from repro.kernels import paged_prefill as _pp
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _paged_impl(impl: str | None) -> str:
+    """Resolve a paged-kernel ``impl``: None -> "pallas" on TPU, "jnp"
+    elsewhere.  The jnp twin is the CPU execution path only — asking for it
+    on a TPU would hide the kernel the chip is meant to run."""
+    if impl is None:
+        return "pallas" if _on_tpu() else "jnp"
+    if impl not in ("pallas", "interpret", "jnp"):
+        raise ValueError(f"unknown paged-attention impl {impl!r}")
+    if impl == "jnp" and _on_tpu():
+        raise ValueError("the jnp paged-attention path is the CPU execution "
+                         "path; on TPU the compiled Pallas kernel runs")
+    return impl
 
 
 @functools.partial(jax.jit, static_argnames=("prefix_len", "window",
@@ -57,7 +73,7 @@ def paged_decode_attention(q, k_pages, v_pages, tables, counts, starts, qpos,
       impl="pallas"    -> force the compiled kernel
       impl="interpret" -> Pallas kernel body in interpret mode (tests: runs
                           the BlockSpec/grid logic bit-for-bit on CPU)
-      impl="jnp"       -> force the jnp path
+      impl="jnp"       -> force the jnp path (refused on TPU)
 
     Not jit-wrapped: this is called per-layer inside the (already jitted)
     decode step's layer scan, where ``layer``/``window`` are traced values.
@@ -71,14 +87,11 @@ def paged_decode_attention(q, k_pages, v_pages, tables, counts, starts, qpos,
     pool planes on KV heads — with head-local block tables (the run tables
     are head-independent, hence replicated verbatim onto every shard).
     """
-    if impl is None:
-        impl = "pallas" if _on_tpu() else "jnp"
+    impl = _paged_impl(impl)
     if impl == "jnp":
         return _pg.paged_decode_jnp(q, k_pages, v_pages, tables, counts,
                                     starts, qpos, layer, window,
                                     logit_cap=logit_cap)
-    if impl not in ("pallas", "interpret"):
-        raise ValueError(f"unknown paged-attention impl {impl!r}")
     if mesh is not None and mesh.shape.get(axis, 1) > 1:
         return _paged_decode_sharded(q, k_pages, v_pages, tables, counts,
                                      starts, qpos, layer, window,
@@ -98,7 +111,6 @@ def _paged_decode_sharded(q, k_pages, v_pages, tables, counts, starts, qpos,
     the shard's (KV/tp)-head pool plane; no collectives — decode attention
     is embarrassingly parallel over heads (the later wo matmul's all-reduce
     belongs to the surrounding GSPMD program)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def local(q_l, kp_l, vp_l, tb, cn, st, qp, li, w):
@@ -107,13 +119,13 @@ def _paged_decode_sharded(q, k_pages, v_pages, tables, counts, starts, qpos,
                                           interpret=interpret)
 
     rep2 = P(None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, axis, None),
-                  P(None, None, None, axis, None),
-                  P(None, None, None, axis, None),
+                  P(None, None, axis, None, None),
+                  P(None, None, axis, None, None),
                   rep2, rep2, rep2, P(None), P(), P()),
-        out_specs=P(None, axis, None), check_rep=False)
+        out_specs=P(None, axis, None), check_vma=False)
     return fn(q, k_pages, v_pages, tables, counts, starts, qpos,
               jnp.asarray(layer, jnp.int32), jnp.asarray(window, jnp.int32))
 
@@ -135,14 +147,11 @@ def paged_prefill_attention(q, k_pages, v_pages, tables, counts, starts,
     pallas/interpret paths dispatch the kernel per shard over head-local
     tiles with replicated run tables.
     """
-    if impl is None:
-        impl = "pallas" if _on_tpu() else "jnp"
+    impl = _paged_impl(impl)
     if impl == "jnp":
         return _pp.paged_prefill_jnp(q, k_pages, v_pages, tables, counts,
                                      starts, q_start, q_len, layer, window,
                                      logit_cap=logit_cap)
-    if impl not in ("pallas", "interpret"):
-        raise ValueError(f"unknown paged-attention impl {impl!r}")
     if mesh is not None and mesh.shape.get(axis, 1) > 1:
         return _paged_prefill_sharded(q, k_pages, v_pages, tables, counts,
                                       starts, q_start, q_len, layer, window,
@@ -161,7 +170,6 @@ def _paged_prefill_sharded(q, k_pages, v_pages, tables, counts, starts,
     """Per-shard Pallas dispatch for prefill: identical scheme to
     ``_paged_decode_sharded`` with the extra Sq query axis riding along
     unsharded — prefill attention is embarrassingly parallel over heads."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def local(q_l, kp_l, vp_l, tb, cn, st, qs, ql, li, w):
@@ -170,12 +178,12 @@ def _paged_prefill_sharded(q, k_pages, v_pages, tables, counts, starts,
                                            interpret=interpret)
 
     rep2 = P(None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, axis, None, None),
-                  P(None, None, None, axis, None),
-                  P(None, None, None, axis, None),
+                  P(None, None, axis, None, None),
+                  P(None, None, axis, None, None),
                   rep2, rep2, rep2, P(None), P(None), P(), P()),
-        out_specs=P(None, axis, None, None), check_rep=False)
+        out_specs=P(None, axis, None, None), check_vma=False)
     return fn(q, k_pages, v_pages, tables, counts, starts, q_start, q_len,
               jnp.asarray(layer, jnp.int32), jnp.asarray(window, jnp.int32))

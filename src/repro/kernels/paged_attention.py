@@ -6,10 +6,14 @@ the TPU-native adaptation prefetches the request's block table into SMEM
 and the DMA engine streams one (page x hd) KV tile HBM->VMEM per grid step
 while the VPU/MXU consumes the previous one.
 
-The kernel operates on the ``PagedKVStore``'s own layer-major layout —
-``k_pages/v_pages: (L, n_pages, page, KV, hd)`` — selecting the layer through
-a prefetched scalar, so the serving runtime's decode step attends IN PLACE:
-no per-iteration dense re-materialization of the cached context.
+The kernel operates on the ``PagedKVStore``'s own layer-major, head-major
+layout — ``k_pages/v_pages: (L, n_pages, KV, page, hd)`` — selecting the
+layer through a prefetched scalar, so the serving runtime's decode step
+attends IN PLACE: no per-iteration dense re-materialization of the cached
+context.  Heads sit outside the page so that the tile the DMA streams has
+``(page, hd)`` as its last two dims: the TPU compiler tiles the minor two
+dims of a block by (8, 128) unless they span the whole array dim, which a
+single KV head picked out of a ``(page, KV, hd)`` page would not.
 
 Token-level slot-mapping contract (what PR 4's unaligned sharing produces,
 see ``serving/runtime.py::_paginate``): a request's sequence is a list of
@@ -59,8 +63,8 @@ def _decode_kernel(meta_ref, tables_ref, counts_ref, starts_ref, qpos_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)          # (1, hd) — one token
-    k = k_ref[0, 0, :, 0].astype(jnp.float32)    # (page, hd)
-    v = v_ref[0, 0, :, 0].astype(jnp.float32)
+    k = k_ref[0, 0, 0].astype(jnp.float32)       # (page, hd)
+    v = v_ref[0, 0, 0].astype(jnp.float32)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -70,7 +74,8 @@ def _decode_kernel(meta_ref, tables_ref, counts_ref, starts_ref, qpos_ref,
     live = slot < counts_ref[b, ib]
     win = meta_ref[1]
     pos = starts_ref[b, ib] + slot
-    live &= jnp.where(win > 0, pos > qpos_ref[b] - win, True)
+    # boolean or, not a select: Mosaic cannot lower a select on i1 vectors
+    live &= (win <= 0) | (pos > qpos_ref[b] - win)
     s = jnp.where(live, s, NEG_INF)
 
     m_prev = m_ref[...]
@@ -94,7 +99,7 @@ def _decode_kernel(meta_ref, tables_ref, counts_ref, starts_ref, qpos_ref,
 
 def paged_decode_attention(
     q: jax.Array,              # (B, H, hd) — one decode token per sequence
-    k_pages: jax.Array,        # (L, n_pages, page, KV, hd) — the pool arrays
+    k_pages: jax.Array,        # (L, n_pages, KV, page, hd) — the pool arrays
     v_pages: jax.Array,
     tables: jax.Array,         # (B, n_slots) int32 page ids (runs, in order)
     counts: jax.Array,         # (B, n_slots) live tokens per run (0 = unused)
@@ -107,7 +112,7 @@ def paged_decode_attention(
     interpret: bool = False,
 ) -> jax.Array:
     B, H, hd = q.shape
-    _, _, page, KV, _ = k_pages.shape
+    _, _, KV, page, _ = k_pages.shape
     R = H // KV
     n_slots = tables.shape[1]
     scale = hd ** -0.5
@@ -123,12 +128,12 @@ def paged_decode_attention(
             pl.BlockSpec((1, 1, 1, hd),
                          lambda b, h, ib, meta, tbl, cnt, st, qp:
                          (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, page, 1, hd),
+            pl.BlockSpec((1, 1, 1, page, hd),
                          lambda b, h, ib, meta, tbl, cnt, st, qp:
-                         (meta[0], tbl[b, ib], 0, h // R, 0)),
-            pl.BlockSpec((1, 1, page, 1, hd),
+                         (meta[0], tbl[b, ib], h // R, 0, 0)),
+            pl.BlockSpec((1, 1, 1, page, hd),
                          lambda b, h, ib, meta, tbl, cnt, st, qp:
-                         (meta[0], tbl[b, ib], 0, h // R, 0)),
+                         (meta[0], tbl[b, ib], h // R, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, 1, hd),
                                lambda b, h, ib, meta, tbl, cnt, st, qp:
@@ -142,6 +147,7 @@ def paged_decode_attention(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        name="paged_decode",
         out_shape=jax.ShapeDtypeStruct((B, H, 1, hd), q.dtype),
         interpret=interpret,
     )(meta, tables, counts, starts, qpos, q[:, :, None], k_pages, v_pages)
@@ -150,7 +156,7 @@ def paged_decode_attention(
 
 def paged_decode_jnp(
     q: jax.Array,              # (B, H, hd)
-    k_pages: jax.Array,        # (L, n_pages, page, KV, hd)
+    k_pages: jax.Array,        # (L, n_pages, KV, page, hd)
     v_pages: jax.Array,
     tables: jax.Array,         # (B, n_slots)
     counts: jax.Array,
@@ -163,11 +169,11 @@ def paged_decode_jnp(
 ) -> jax.Array:
     """Per-page gather + online softmax, pure jnp (the CPU execution path).
 
-    Peak live memory per step is one (B, page, KV, hd) tile — never the
+    Peak live memory per step is one (B, KV, page, hd) tile — never the
     dense (B, S, KV, hd) context, let alone all L layers of it.
     """
     B, H, hd = q.shape
-    page, KV = k_pages.shape[2], k_pages.shape[3]
+    KV, page = k_pages.shape[2], k_pages.shape[3]
     R = H // KV
     scale = hd ** -0.5
     qf = (q.astype(jnp.float32) * scale).reshape(B, KV, R, hd)
@@ -178,9 +184,9 @@ def paged_decode_jnp(
     def body(carry, j):
         m, l, acc = carry
         pid = tables[:, j]                                 # (B,)
-        k = k_pages[layer, pid].astype(jnp.float32)        # (B, page, KV, hd)
+        k = k_pages[layer, pid].astype(jnp.float32)        # (B, KV, page, hd)
         v = v_pages[layer, pid].astype(jnp.float32)
-        s = jnp.einsum("bgrd,bpgd->bgrp", qf, k)
+        s = jnp.einsum("bgrd,bgpd->bgrp", qf, k)
         if logit_cap:
             s = logit_cap * jnp.tanh(s / logit_cap)
         live = slot[None, :] < counts[:, j, None]          # (B, page)
@@ -192,7 +198,7 @@ def paged_decode_jnp(
         p = jnp.where(lb, jnp.exp(s - m_new[..., None]), 0.0)
         alpha = jnp.exp(m - m_new)
         l = l * alpha + p.sum(axis=-1)
-        acc = acc * alpha[..., None] + jnp.einsum("bgrp,bpgd->bgrd", p, v)
+        acc = acc * alpha[..., None] + jnp.einsum("bgrp,bgpd->bgrd", p, v)
         return (m_new, l, acc), None
 
     init = (jnp.full((B, KV, R), NEG_INF, jnp.float32),
@@ -205,7 +211,7 @@ def paged_decode_jnp(
 
 def paged_attention(
     q: jax.Array,              # (B, H, hd)
-    k_pages: jax.Array,        # (n_pages, page, KV, hd) — single-layer view
+    k_pages: jax.Array,        # (n_pages, KV, page, hd) — single-layer view
     v_pages: jax.Array,
     block_tables: jax.Array,   # (B, n_slots) int32 page ids
     lengths: jax.Array,        # (B,) valid token counts
@@ -215,7 +221,7 @@ def paged_attention(
     """Single-layer convenience wrapper over the layer-major kernel:
     contiguous semantics (page ``j`` holds positions ``[j*page, ...)`` up to
     ``lengths[b]``), kept for the kernel parity sweep and benches."""
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     n_slots = block_tables.shape[1]
     off = jnp.arange(n_slots, dtype=jnp.int32)[None] * page      # (1, n_slots)
     counts = jnp.clip(lengths[:, None] - off, 0, page).astype(jnp.int32)

@@ -2,8 +2,9 @@
 (docs/ARCHITECTURE.md §3) — the prefill twin of ``paged_attention.py``.
 
 A prefill chunk's queries attend over (a) the cached prefix pages already
-resident in the ``PagedKVStore``'s layer-major ``(L, n_pages, page, KV, hd)``
-planes and (b) the chunk's own new KV, which the model step scatters into its
+resident in the ``PagedKVStore``'s layer-major, head-major
+``(L, n_pages, KV, page, hd)`` planes (``(page, hd)`` minor, the tile shape
+the TPU compiler accepts — see ``paged_attention.py``) and (b) the chunk's own new KV, which the model step scatters into its
 freshly allocated pages *before* calling attention.  Both live behind the
 same run-table slot-mapping contract as paged decode (``tables/counts/
 starts``: page ``tables[b, j]`` holds ``counts[b, j]`` consecutive tokens
@@ -58,8 +59,8 @@ def _prefill_kernel(meta_ref, tables_ref, counts_ref, starts_ref, qstart_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)          # (block_q, hd)
-    k = k_ref[0, 0, :, 0].astype(jnp.float32)    # (page, hd)
-    v = v_ref[0, 0, :, 0].astype(jnp.float32)
+    k = k_ref[0, 0, 0].astype(jnp.float32)       # (page, hd)
+    v = v_ref[0, 0, 0].astype(jnp.float32)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -74,7 +75,8 @@ def _prefill_kernel(meta_ref, tables_ref, counts_ref, starts_ref, qstart_ref,
     live &= kpos <= qpos                         # causal, absolute positions
     live &= qrow < qlen_ref[b]                   # ragged-padding query rows
     win = meta_ref[1]
-    live &= jnp.where(win > 0, kpos > qpos - win, True)
+    # boolean or, not a select: Mosaic cannot lower a select on i1 vectors
+    live &= (win <= 0) | (kpos > qpos - win)
     s = jnp.where(live, s, NEG_INF)
 
     m_prev = m_ref[...]
@@ -98,7 +100,7 @@ def _prefill_kernel(meta_ref, tables_ref, counts_ref, starts_ref, qstart_ref,
 
 def paged_prefill_attention(
     q: jax.Array,              # (B, H, Sq, hd) — one prefill chunk per row
-    k_pages: jax.Array,        # (L, n_pages, page, KV, hd) — the pool arrays
+    k_pages: jax.Array,        # (L, n_pages, KV, page, hd) — the pool arrays
     v_pages: jax.Array,
     tables: jax.Array,         # (B, n_slots) int32 page ids (runs, in order)
     counts: jax.Array,         # (B, n_slots) live tokens per run (0 = unused)
@@ -113,7 +115,7 @@ def paged_prefill_attention(
     interpret: bool = False,
 ) -> jax.Array:
     B, H, Sq, hd = q.shape
-    _, _, page, KV, _ = k_pages.shape
+    _, _, KV, page, _ = k_pages.shape
     R = H // KV
     n_slots = tables.shape[1]
     scale = hd ** -0.5
@@ -136,12 +138,12 @@ def paged_prefill_attention(
             pl.BlockSpec((1, 1, block_q, hd),
                          lambda b, h, iq, ib, meta, tbl, cnt, st, qs, ql:
                          (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, page, 1, hd),
+            pl.BlockSpec((1, 1, 1, page, hd),
                          lambda b, h, iq, ib, meta, tbl, cnt, st, qs, ql:
-                         (meta[0], tbl[b, ib], 0, h // R, 0)),
-            pl.BlockSpec((1, 1, page, 1, hd),
+                         (meta[0], tbl[b, ib], h // R, 0, 0)),
+            pl.BlockSpec((1, 1, 1, page, hd),
                          lambda b, h, iq, ib, meta, tbl, cnt, st, qs, ql:
-                         (meta[0], tbl[b, ib], 0, h // R, 0)),
+                         (meta[0], tbl[b, ib], h // R, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, hd),
                                lambda b, h, iq, ib, meta, tbl, cnt, st, qs, ql:
@@ -155,6 +157,7 @@ def paged_prefill_attention(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        name="paged_prefill",
         out_shape=jax.ShapeDtypeStruct((B, H, Sq + pad_q, hd), q.dtype),
         interpret=interpret,
     )(meta, tables, counts, starts, q_start, q_len, q, k_pages, v_pages)
@@ -163,7 +166,7 @@ def paged_prefill_attention(
 
 def paged_prefill_jnp(
     q: jax.Array,              # (B, H, Sq, hd)
-    k_pages: jax.Array,        # (L, n_pages, page, KV, hd)
+    k_pages: jax.Array,        # (L, n_pages, KV, page, hd)
     v_pages: jax.Array,
     tables: jax.Array,         # (B, n_slots)
     counts: jax.Array,
@@ -177,12 +180,12 @@ def paged_prefill_jnp(
 ) -> jax.Array:
     """Per-page gather + online softmax, pure jnp (the CPU execution path).
 
-    Peak live memory per step is one (B, page, KV, hd) KV tile plus the
+    Peak live memory per step is one (B, KV, page, hd) KV tile plus the
     (B, H, Sq, page) score tile — never the dense (B, S, KV, hd) context,
     let alone all L layers of it.
     """
     B, H, Sq, hd = q.shape
-    page, KV = k_pages.shape[2], k_pages.shape[3]
+    KV, page = k_pages.shape[2], k_pages.shape[3]
     R = H // KV
     scale = hd ** -0.5
     qf = (q.astype(jnp.float32) * scale).reshape(B, KV, R, Sq, hd)
@@ -196,9 +199,9 @@ def paged_prefill_jnp(
     def body(carry, j):
         m, l, acc = carry
         pid = tables[:, j]                                 # (B,)
-        k = k_pages[layer, pid].astype(jnp.float32)        # (B, page, KV, hd)
+        k = k_pages[layer, pid].astype(jnp.float32)        # (B, KV, page, hd)
         v = v_pages[layer, pid].astype(jnp.float32)
-        s = jnp.einsum("bgrqd,bpgd->bgrqp", qf, k)
+        s = jnp.einsum("bgrqd,bgpd->bgrqp", qf, k)
         if logit_cap:
             s = logit_cap * jnp.tanh(s / logit_cap)
         kpos = starts[:, j, None] + slot[None]             # (B, page)
@@ -213,7 +216,7 @@ def paged_prefill_jnp(
         p = jnp.where(mb, jnp.exp(s - m_new[..., None]), 0.0)
         alpha = jnp.exp(m - m_new)
         l = l * alpha + p.sum(axis=-1)
-        acc = acc * alpha[..., None] + jnp.einsum("bgrqp,bpgd->bgrqd", p, v)
+        acc = acc * alpha[..., None] + jnp.einsum("bgrqp,bgpd->bgrqd", p, v)
         return (m_new, l, acc), None
 
     init = (jnp.full((B, KV, R, Sq), NEG_INF, jnp.float32),

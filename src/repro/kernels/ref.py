@@ -32,17 +32,17 @@ def reference_paged_prefill(q, k_pages, v_pages, tables, counts, starts,
                             q_start, q_len, layer, window=0, logit_cap=0.0):
     """Dense oracle for the layer-major paged prefill kernel.
 
-    q: (B, H, Sq, hd); k/v_pages: (L, n_pages, page, KV, hd); tables/counts/
+    q: (B, H, Sq, hd); k/v_pages: (L, n_pages, KV, page, hd); tables/counts/
     starts: (B, n_slots) run descriptors (see paged_attention.py docstring);
     q_start: (B,) absolute position of query row 0; q_len: (B,) valid query
     rows — invalid (ragged-padding) rows return exact zeros.
     """
     B, H, Sq, hd = q.shape
-    page, KV = k_pages.shape[2], k_pages.shape[3]
+    KV, page = k_pages.shape[2], k_pages.shape[3]
     R = H // KV
     nb = tables.shape[1]
-    k = k_pages[layer][tables]           # (B, nb, page, KV, hd)
-    v = v_pages[layer][tables]
+    k = k_pages[layer][tables].swapaxes(2, 3)    # (B, nb, page, KV, hd)
+    v = v_pages[layer][tables].swapaxes(2, 3)
     k = k.reshape(B, nb * page, KV, hd)
     v = v.reshape(B, nb * page, KV, hd)
     kf = jnp.repeat(k, R, axis=2).astype(jnp.float32)
@@ -70,16 +70,16 @@ def reference_paged_decode(q, k_pages, v_pages, tables, counts, starts, qpos,
                            layer, window=0, logit_cap=0.0):
     """Dense oracle for the layer-major paged decode kernel.
 
-    q: (B, H, hd); k/v_pages: (L, n_pages, page, KV, hd); tables/counts/
+    q: (B, H, hd); k/v_pages: (L, n_pages, KV, page, hd); tables/counts/
     starts: (B, n_slots) run descriptors (see paged_attention.py docstring);
     qpos: (B,) absolute query position; layer selects the page plane.
     """
     B, H, hd = q.shape
-    page, KV = k_pages.shape[2], k_pages.shape[3]
+    KV, page = k_pages.shape[2], k_pages.shape[3]
     R = H // KV
     nb = tables.shape[1]
-    k = k_pages[layer][tables]           # (B, nb, page, KV, hd)
-    v = v_pages[layer][tables]
+    k = k_pages[layer][tables].swapaxes(2, 3)    # (B, nb, page, KV, hd)
+    v = v_pages[layer][tables].swapaxes(2, 3)
     k = k.reshape(B, nb * page, KV, hd)
     v = v.reshape(B, nb * page, KV, hd)
     kf = jnp.repeat(k, R, axis=2).astype(jnp.float32)
@@ -100,15 +100,15 @@ def reference_paged_decode(q, k_pages, v_pages, tables, counts, starts, qpos,
 
 
 def reference_paged_attention(q, k_pages, v_pages, block_tables, lengths):
-    """q: (B, H, hd); k/v_pages: (n_pages, page, KV, hd);
+    """q: (B, H, hd); k/v_pages: (n_pages, KV, page, hd);
     block_tables: (B, n_blocks_max) int32; lengths: (B,) valid tokens."""
     B, H, hd = q.shape
-    n_pages, page, KV, _ = k_pages.shape
+    n_pages, KV, page, _ = k_pages.shape
     R = H // KV
     nb = block_tables.shape[1]
     # gather per-request contiguous KV
-    k = k_pages[block_tables]            # (B, nb, page, KV, hd)
-    v = v_pages[block_tables]
+    k = k_pages[block_tables].swapaxes(2, 3)     # (B, nb, page, KV, hd)
+    v = v_pages[block_tables].swapaxes(2, 3)
     k = k.reshape(B, nb * page, KV, hd)
     v = v.reshape(B, nb * page, KV, hd)
     kf = jnp.repeat(k, R, axis=2).astype(jnp.float32)

@@ -1,10 +1,14 @@
 """Paged KV block pool (vLLM-style, paper §2/§5.1: "RAGCache stores the
 key-value tensors in non-contiguous memory blocks").
 
-The pool owns a big (n_blocks, block_size, ...) buffer per tier; documents
-hold block-id lists.  Ref-counting lets overlapping knowledge-tree paths
-share blocks.  ``gather``/``scatter`` convert between paged storage and the
-contiguous (B, S, KV, hd) layout the model functions consume.
+The pool owns a big (L, n_blocks, KV, block_size, hd) buffer per tier;
+documents hold block-id lists.  Ref-counting lets overlapping knowledge-tree
+paths share blocks.  ``put``/``gather`` convert between paged storage and
+the contiguous (L, 1, T, KV, hd) segment layout the model functions, the
+host tier and the disk tier use.  The pool is head-major — each page of one
+KV head is a contiguous (block_size, hd) tile — because that is the tile
+the paged Pallas kernels stream, and the TPU compiler only accepts a block
+whose two minor dims are (page, hd).
 
 ``DiskSegmentStore`` is the third tier below the dense host copies: one
 mmap file per knowledge-tree node (docs/ARCHITECTURE.md §2).  Segments are
@@ -19,13 +23,28 @@ import itertools
 import os
 from typing import List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-try:
-    import jax
-    import jax.numpy as jnp
-except Exception:                                    # pragma: no cover
-    jax = None
+
+def gather_slots(pages, blk, slot):
+    """Token-level read of pool planes: ``pages`` (L, n_blocks, KV, block,
+    hd); ``blk``/``slot`` int arrays of one shape S -> (L, *S, KV, hd)."""
+    xp = np if isinstance(pages, np.ndarray) else jnp
+    # a slice between the two index arrays puts their dims first
+    return xp.moveaxis(pages[:, blk, :, slot], np.ndim(blk), 0)
+
+
+def scatter_slots(pages, blk, slot, vals):
+    """Token-level write, the inverse of ``gather_slots``: ``vals``
+    (L, *S, KV, hd) lands at ``(blk, slot)``.  Returns the updated planes
+    (numpy planes are written in place)."""
+    if isinstance(pages, np.ndarray):
+        pages[:, blk, :, slot] = np.moveaxis(np.asarray(vals), 0, np.ndim(blk))
+        return pages
+    vals = jnp.moveaxis(vals, 0, np.ndim(blk)).astype(pages.dtype)
+    return pages.at[:, blk, :, slot].set(vals)
 
 
 class OutOfBlocks(RuntimeError):
@@ -82,53 +101,52 @@ class BlockPool:
 class PagedKVStore:
     """Paged storage for per-document KV segments.
 
-    Layout: k/v buffers of shape (L, n_blocks, block_size, KV, hd).  A stored
+    Layout: k/v buffers of shape (L, n_blocks, KV, block_size, hd).  A stored
     segment is (block_ids, n_tokens).  numpy backing doubles as the host tier;
     jnp backing is the device tier.
     """
 
     def __init__(self, n_layers: int, n_blocks: int, block_size: int,
                  n_kv: int, head_dim: int, dtype=np.float32,
-                 device: bool = False, kv_sharding=None):
+                 device: bool = False, sharding=None):
         self.pool = BlockPool(n_blocks, block_size)
         self.block_size = block_size
-        shape = (n_layers, n_blocks, block_size, n_kv, head_dim)
-        self.device = device and jax is not None
-        # Tensor-parallel serving: a NamedSharding over the KV-head dim
-        # (launch/sharding.py::pool_kv_spec) — the pool planes are created
-        # sharded, and everything written into them (put/append) lands
-        # shard-local, so no plane is ever materialized on one device.
-        self.kv_sharding = kv_sharding if self.device else None
+        shape = (n_layers, n_blocks, n_kv, block_size, head_dim)
+        self.device = device
+        # Where the device planes live: None = the default device; a
+        # SingleDeviceSharding pins one replica's pool to its own chip; a
+        # NamedSharding over the KV-head dim (launch/sharding.py::
+        # pool_kv_spec) splits it for tensor-parallel serving.  The planes
+        # are created in place and everything written into them (put/append)
+        # lands there, so no plane is ever materialized on another device.
+        self.sharding = sharding if self.device else None
         if self.device:
-            self.k = jnp.zeros(shape, dtype)
-            self.v = jnp.zeros(shape, dtype)
-            if self.kv_sharding is not None:
-                self.k = jax.device_put(self.k, self.kv_sharding)
-                self.v = jax.device_put(self.v, self.kv_sharding)
+            self.k = jnp.zeros(shape, dtype, device=self.sharding)
+            self.v = jnp.zeros(shape, dtype, device=self.sharding)
         else:
             self.k = np.zeros(shape, dtype)
             self.v = np.zeros(shape, dtype)
 
-    def _shard_segment(self, k_seg, v_seg):
-        """Promotion path of a sharded pool: place an incoming contiguous
-        (L, B, T, KV, hd) segment with its KV heads split the same way the
-        pool is, so the host->device copy is BATCHED per mesh-axis member —
-        each device receives exactly its head slice, instead of a full
-        replica that the next pool write would reshard collectively."""
-        if self.kv_sharding is None or not self.device:
+    def _place_segment(self, k_seg, v_seg):
+        """Promotion path of a placed pool: copy an incoming host (numpy)
+        (L, B, T, KV, hd) segment straight to where the pool lives.  A
+        sharded pool gets it with its KV heads split the same way, so the
+        host->device copy is BATCHED per mesh-axis member — each device
+        receives exactly its head slice, instead of a full replica that the
+        next pool write would reshard collectively."""
+        if self.sharding is None or not isinstance(k_seg, np.ndarray):
+            # default-device pool, or a device-computed segment (prefill
+            # cache slice) that is already where it was computed
             return k_seg, v_seg
-        if not isinstance(k_seg, np.ndarray):
-            # device-computed segment (prefill cache slice): GSPMD already
-            # placed its KV heads; the pool write reshards if needed
-            return k_seg, v_seg
-        seg_sh = jax.sharding.NamedSharding(
-            self.kv_sharding.mesh,
-            jax.sharding.PartitionSpec(None, None, None,
-                                       *self.kv_sharding.spec[3:]))
+        seg_sh = self.sharding
+        if isinstance(seg_sh, jax.sharding.NamedSharding):
+            seg_sh = jax.sharding.NamedSharding(
+                seg_sh.mesh,
+                jax.sharding.PartitionSpec(None, None, None, seg_sh.spec[2]))
         return jax.device_put(k_seg, seg_sh), jax.device_put(v_seg, seg_sh)
 
     def bytes_per_token(self) -> int:
-        L, _, _, KV, hd = self.k.shape
+        L, _, KV, _, hd = self.k.shape
         return int(2 * L * KV * hd * self.k.dtype.itemsize)
 
     def put(self, k_seg, v_seg, reserve_tokens: int = 0) -> "PagedSegment":
@@ -142,11 +160,13 @@ class PagedKVStore:
         blocks = self.pool.alloc(nb)
         pad = nb * self.block_size - T
         if self.device:
-            k_seg, v_seg = self._shard_segment(k_seg, v_seg)
+            k_seg, v_seg = self._place_segment(k_seg, v_seg)
             ks = jnp.pad(k_seg[:, 0], ((0, 0), (0, pad), (0, 0), (0, 0)))
             vs = jnp.pad(v_seg[:, 0], ((0, 0), (0, pad), (0, 0), (0, 0)))
-            ks = ks.reshape(ks.shape[0], nb, self.block_size, *ks.shape[2:])
-            vs = vs.reshape(vs.shape[0], nb, self.block_size, *vs.shape[2:])
+            ks = ks.reshape(ks.shape[0], nb, self.block_size,
+                            *ks.shape[2:]).swapaxes(2, 3)
+            vs = vs.reshape(vs.shape[0], nb, self.block_size,
+                            *vs.shape[2:]).swapaxes(2, 3)
             idx = jnp.asarray(blocks)
             self.k = self.k.at[:, idx].set(ks.astype(self.k.dtype))
             self.v = self.v.at[:, idx].set(vs.astype(self.v.dtype))
@@ -158,8 +178,8 @@ class PagedKVStore:
                 hi = min(lo + self.block_size, T)
                 if hi <= lo:            # reserve-only tail block
                     break
-                self.k[:, b, : hi - lo] = k_seg[:, 0, lo:hi]
-                self.v[:, b, : hi - lo] = v_seg[:, 0, lo:hi]
+                self.k[:, b, :, : hi - lo] = k_seg[:, 0, lo:hi].swapaxes(1, 2)
+                self.v[:, b, :, : hi - lo] = v_seg[:, 0, lo:hi].swapaxes(1, 2)
         return PagedSegment(self, blocks, T)
 
     def append(self, seg: "PagedSegment", k_new, v_new) -> "PagedSegment":
@@ -183,17 +203,9 @@ class PagedKVStore:
         blk = np.asarray(seg.blocks, np.int64)[pos // self.block_size]
         slot = pos % self.block_size
         if self.device:
-            k_new, v_new = self._shard_segment(k_new, v_new)
-            bi = jnp.asarray(blk)
-            si = jnp.asarray(slot)
-            self.k = self.k.at[:, bi, si].set(k_new[:, 0].astype(self.k.dtype))
-            self.v = self.v.at[:, bi, si].set(v_new[:, 0].astype(self.v.dtype))
-        else:
-            k_new = np.asarray(k_new)
-            v_new = np.asarray(v_new)
-            for t in range(T):
-                self.k[:, blk[t], slot[t]] = k_new[:, 0, t]
-                self.v[:, blk[t], slot[t]] = v_new[:, 0, t]
+            k_new, v_new = self._place_segment(k_new, v_new)
+        self.k = scatter_slots(self.k, blk, slot, k_new[:, 0])
+        self.v = scatter_slots(self.v, blk, slot, v_new[:, 0])
         seg.n_tokens += T
         return seg
 
@@ -222,8 +234,8 @@ class PagedKVStore:
         """Paged -> contiguous (L, 1, T, KV, hd)."""
         idx = (jnp.asarray(seg.blocks) if self.device
                else np.asarray(seg.blocks, np.int64))
-        k = self.k[:, idx]        # (L, nb, bs, KV, hd)
-        v = self.v[:, idx]
+        k = self.k[:, idx].swapaxes(2, 3)        # (L, nb, bs, KV, hd)
+        v = self.v[:, idx].swapaxes(2, 3)
         L, nb, bs, KV, hd = k.shape
         k = k.reshape(L, nb * bs, KV, hd)[:, : seg.n_tokens]
         v = v.reshape(L, nb * bs, KV, hd)[:, : seg.n_tokens]

@@ -25,8 +25,7 @@ from pathlib import Path
 import jax
 
 from repro.configs import ASSIGNED, get_config
-from repro.launch.hlo_analysis import (COLLECTIVES, analyze,
-                                       normalize_cost_analysis)
+from repro.launch.hlo_analysis import COLLECTIVES, analyze
 from repro.launch.mesh import make_production_mesh
 from repro.launch.sharding import spec_summary
 from repro.launch.specs import SHAPES, input_specs, shape_applicable
@@ -165,7 +164,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, out_dir: Path,
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower
             mem = compiled.memory_analysis()
-            cost = normalize_cost_analysis(compiled.cost_analysis())
+            cost = compiled.cost_analysis()
             totals = analyze(compiled.as_text())
         rl = roofline(totals, cost or {}, n_chips, cfg, shape_name)
         rec.update(

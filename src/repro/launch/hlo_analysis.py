@@ -43,14 +43,6 @@ COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                "collective-permute")
 
 
-def normalize_cost_analysis(ca) -> dict:
-    """``compiled.cost_analysis()`` returns a dict on older jaxlib and a
-    one-element list of dicts on newer jaxlib; normalize to a dict."""
-    if isinstance(ca, (list, tuple)):
-        return ca[0] if ca else {}
-    return ca or {}
-
-
 def _shape_elems(dims: str) -> int:
     n = 1
     for d in dims.split(","):

@@ -44,27 +44,38 @@ construction.
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b \
         --requests 12 --docs 50 --top-k 2 [--policy lru] [--no-reorder] \
-        [--sequential] [--check-tokens] \
+        [--published] [--sequential] [--check-tokens] \
         [--replicas N --routing {affinity,round_robin,least_loaded}] \
         [--gpu-cache-bytes N --host-cache-bytes N \
          --disk-cache-bytes N --disk-cache-dir DIR]
 
-Uses the reduced config (CPU-sized); the production configs are exercised
-through launch/dryrun.py.  SSM/hybrid families always use the sequential
-engine (recurrent state cannot be paged per-block).
+By default the arch's reduced config is served (CPU-sized: 2 layers, narrow
+widths); ``--published`` serves its published config (configs/*: every
+layer at full width, random weights from ``--seed``), which is what runs on
+the chip.  Replica ``i`` owns devices ``[i*tp, (i+1)*tp)`` (wrapping when
+the fleet has more replicas than devices).  SSM/hybrid families always use
+the sequential engine (recurrent state cannot be paged per-block).
+
+JAX's persistent compilation cache lives in ``$JAX_COMPILATION_CACHE_DIR``
+when that is set, else in ``.jax_cache`` at the root of the checkout.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
+import os
+import pathlib
 import time
+from typing import List, Optional
 
 import jax
 import numpy as np
 
-from repro.configs import get_reduced
+from repro.configs import get_config, get_reduced
 from repro.launch.mesh import make_serving_mesh
-from repro.launch.sharding import assert_tp_compatible, spec_summary
+from repro.launch.sharding import (assert_tp_compatible,
+                                   serving_param_shardings, spec_summary)
 from repro.models import model as M
 from repro.retrieval.corpus import make_corpus, make_workload
 from repro.retrieval.traffic import make_default_workload
@@ -79,9 +90,29 @@ from repro.serving.router import (ROUTING_POLICIES, ReplicaRouter,
 from repro.serving.runtime import ContinuousRuntime
 
 
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def setup_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and no
+    other directory is set here; otherwise the cache goes to ``.jax_cache``
+    at the root of the checkout — a fixed path, since the path is part of
+    what a cache hit needs.  Call before the first compile."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(REPO_ROOT / ".jax_cache"))
+    return jax.config.jax_compilation_cache_dir
+
+
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
+    ap.add_argument("--published", action="store_true",
+                    help="serve the arch's published config (every layer at "
+                         "full width; random weights from --seed) instead "
+                         "of the reduced CPU-sized one")
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--docs", type=int, default=50)
     ap.add_argument("--doc-tokens", type=int, default=32)
@@ -263,12 +294,43 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
+def model_config(args):
+    """The served ModelConfig: published (--published) or reduced."""
+    return (get_config if args.published else get_reduced)(args.arch)
+
+
+def replica_devices(i: int, tp: int) -> list:
+    """Replica ``i``'s chips: ``tp`` consecutive devices, wrapping around
+    when the fleet has more replicas than devices (CPU tests)."""
+    devs = jax.devices()
+    if tp > len(devs):
+        raise ValueError(
+            f"--tp {tp} needs {tp} devices but only {len(devs)} are visible; "
+            f"on CPU set XLA_FLAGS=--xla_force_host_platform_device_count="
+            f"{tp} (before the first jax import)")
+    return [devs[(i * tp + j) % len(devs)] for j in range(tp)]
+
+
+def init_params(cfg, seed: int, tp: int = 1):
+    """Random weights from ``seed``, created where replica 0 serves them:
+    on the default device, or (tp > 1) already sharded over replica 0's
+    mesh — a model too large for one chip is never gathered onto one.
+    Threefry keys are partitionable, so the values do not depend on tp."""
+    fn = functools.partial(M.init_params, cfg)
+    key = jax.random.PRNGKey(seed)
+    if tp == 1:
+        return jax.jit(fn)(key)
+    mesh = make_serving_mesh(tp, replica_devices(0, tp))
+    shardings = serving_param_shardings(cfg, jax.eval_shape(fn, key), mesh)
+    return jax.jit(fn, out_shardings=shardings)(key)
+
+
 def make_setup(args):
     """Build (cfg, params, corpus, idx, workload, tenants).  ``tenants`` is
     the TenantSpec list when --tenants > 0 (multi-tenant traffic model),
     else None (single-tenant stationary make_workload)."""
-    cfg = get_reduced(args.arch)
-    params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
+    cfg = model_config(args)
+    params = init_params(cfg, args.seed, args.tp)
     corpus = make_corpus(args.docs, mean_doc_tokens=args.doc_tokens,
                          vocab=cfg.vocab_size, seed=args.seed)
     idx = IVFIndex(corpus.doc_vectors, n_clusters=min(16, args.docs),
@@ -320,6 +382,22 @@ def parse_check_mode(value):
                      f"(use 'exact' or 'tol:<eps>')")
 
 
+def _linf(a, b) -> Optional[float]:
+    """First-token logit L-inf of two results; None unless both carry
+    logits."""
+    if a.first_logits is None or b.first_logits is None:
+        return None
+    return float(np.max(np.abs(np.asarray(a.first_logits, np.float64)
+                               - np.asarray(b.first_logits, np.float64))))
+
+
+def first_logit_linf(pairs) -> Optional[float]:
+    """Largest first-token logit L-inf over (continuous, oracle) result
+    pairs that both carry logits; None when no pair does."""
+    d = [x for x in (_linf(a, b) for a, b in pairs) if x is not None]
+    return max(d) if d else None
+
+
 def token_mismatches(pairs, mode, eps):
     """Compare (continuous, sequential) result pairs under a check mode.
 
@@ -330,11 +408,8 @@ def token_mismatches(pairs, mode, eps):
     for a, b in pairs:
         if list(a.tokens) == list(b.tokens):
             continue
-        if mode == "tol" and a.first_logits is not None \
-                and b.first_logits is not None:
-            linf = float(np.max(np.abs(
-                np.asarray(a.first_logits, np.float64)
-                - np.asarray(b.first_logits, np.float64))))
+        linf = _linf(a, b) if mode == "tol" else None
+        if linf is not None:
             if linf <= eps:
                 continue
             bad.append((a.req_id, list(a.tokens), list(b.tokens), linf))
@@ -348,6 +423,7 @@ def serve_sequential(cfg, params, corpus, idx, wl, args, econf=None):
     # same EngineConfig but deliberately ignores config.mesh, so
     # --check-tokens compares sharded continuous vs unsharded sequential.
     econf = econf if econf is not None else EngineConfig.from_args(args)
+    params = jax.device_put(params, jax.devices()[0])
     srv = RAGServer(cfg, params, corpus, idx, config=econf)
     _print_preload(srv)
     t0 = time.time()
@@ -381,9 +457,12 @@ def _print_preload(engine, n_replicas: int = 1) -> None:
 
 
 def make_runtimes(cfg, params, corpus, idx, args, n, econf=None):
+    """``n`` continuous runtimes, replica ``i`` placed on
+    ``replica_devices(i, tp)``: its params and paged pool live there."""
     econf = econf if econf is not None else EngineConfig.from_args(args)
-    return [ContinuousRuntime(cfg, params, corpus, idx, config=econf)
-            for _ in range(n)]
+    return [ContinuousRuntime(cfg, params, corpus, idx, config=econf,
+                              devices=replica_devices(i, econf.mesh.tp))
+            for i in range(n)]
 
 
 def serve_continuous(cfg, params, corpus, idx, wl, args, econf=None,
@@ -434,7 +513,7 @@ def serve_continuous(cfg, params, corpus, idx, wl, args, econf=None,
         print(fleet.format_report())
         for i, rt in enumerate(rts):
             print(f"replica{i} {tier_hit_line(rt.tree)}")
-    return results
+    return results, rts
 
 
 def build_frontdoor(args, tenants, fdc=None):
@@ -507,11 +586,43 @@ def serve_frontdoor(cfg, params, corpus, idx, wl, tenants, args, econf=None,
     if part.warmed:
         for i, b in sorted(part.warmed.items()):
             print(f"scale-up warmed replica{i}: {b} B from disk tier")
-    return results, part
+    return results, part, rts
 
 
-def main() -> None:
-    args = build_parser().parse_args()
+@dataclasses.dataclass
+class ServeOutcome:
+    """What ``main`` served: the config, the engine results by req_id, the
+    continuous runtimes that produced them (empty when only the sequential
+    engine ran), and the first-token logit L-inf against the sequential
+    oracle (--check-tokens only)."""
+    cfg: object
+    results: list
+    runtimes: List[ContinuousRuntime]
+    linf: Optional[float] = None
+
+
+def check_tokens(cont, seq, args, noun: str, note: str) -> float:
+    """--check-tokens: compare continuous results against the sequential
+    oracle's, print the verdict and the first-token logit L-inf, and exit
+    non-zero on a mismatch.  Returns the L-inf."""
+    mode, eps = parse_check_mode(args.check_tokens)
+    seq_by_id = {r.req_id: r for r in seq}
+    pairs = [(a, seq_by_id[a.req_id]) for a in cont]
+    linf = first_logit_linf(pairs)
+    same = sum(list(a.tokens) == list(b.tokens) for a, b in pairs)
+    print(f"{same}/{len(pairs)} requests with identical tokens; first-token "
+          f"logit L-inf vs the sequential oracle: max {linf}")
+    mismatches = token_mismatches(pairs, mode, eps)
+    if mismatches:
+        raise SystemExit(f"token mismatch: {mismatches}")
+    what = "identical" if mode == "exact" else f"within tol {eps:g}"
+    print(f"\ntoken check: all {len(cont)} {noun} {what} ({note})")
+    return linf
+
+
+def main(argv=None) -> ServeOutcome:
+    setup_compile_cache()
+    args = build_parser().parse_args(argv)
     # the config dataclasses are built ONCE from argparse here and threaded
     # through every constructor below — config= is the SOLE constructor
     # API; loose kwargs raise TypeError (serving/config.py,
@@ -523,7 +634,8 @@ def main() -> None:
         # validate head divisibility BEFORE any device work or device-count
         # check, so a bad --arch/--tp pair fails fast on any machine
         try:
-            assert_tp_compatible(get_reduced(args.arch), econf.mesh.tp)
+            assert_tp_compatible(model_config(args), econf.mesh.tp)
+            replica_devices(0, econf.mesh.tp)
         except ValueError as e:
             raise SystemExit(f"--tp {econf.mesh.tp}: {e}")
     cfg, params, corpus, idx, wl, tenants = make_setup(args)
@@ -542,10 +654,8 @@ def main() -> None:
         print(f"tensor parallel: tp={econf.mesh.tp} over a "
               f"(1, {econf.mesh.tp}) mesh "
               f"({jax.local_device_count()} devices visible)")
-        try:
-            smesh = make_serving_mesh(econf.mesh.tp)
-        except RuntimeError as e:  # not enough devices: clean one-liner
-            raise SystemExit(str(e))
+        smesh = make_serving_mesh(econf.mesh.tp,
+                                  replica_devices(0, econf.mesh.tp))
         print(spec_summary(cfg, smesh, params))
 
     recurrent = cfg.family in ("ssm", "hybrid")
@@ -563,46 +673,35 @@ def main() -> None:
         print("note: --tp applies to the continuous engine only; the "
               "sequential engine is the single-device token oracle")
     if args.frontdoor and not recurrent and not args.sequential:
-        miss_results, part = serve_frontdoor(cfg, params, corpus, idx, wl,
-                                             tenants, args, econf=econf,
-                                             fleet_conf=fleet_conf, fdc=fdc)
+        miss_results, part, rts = serve_frontdoor(
+            cfg, params, corpus, idx, wl, tenants, args, econf=econf,
+            fleet_conf=fleet_conf, fdc=fdc)
+        out = ServeOutcome(cfg, miss_results, rts)
         if args.check_tokens:
             # compare ONLY admitted misses (the requests an engine actually
             # served, with the front door's top_k rewrites applied); hits
             # are answered from cache and shed requests never execute
-            mode, eps = parse_check_mode(args.check_tokens)
             seq = serve_sequential(cfg, params, corpus, idx,
                                    list(part.misses), args, econf=econf)
-            seq_by_id = {r.req_id: r for r in seq}
-            mismatches = token_mismatches(
-                [(a, seq_by_id[a.req_id]) for a in miss_results], mode, eps)
-            if mismatches:
-                raise SystemExit(f"token mismatch: {mismatches}")
-            what = ("identical" if mode == "exact"
-                    else f"within tol {eps:g}")
-            print(f"\ntoken check: all {len(miss_results)} front-door miss "
-                  f"requests {what} (continuous vs sequential; "
-                  f"{len(part.hits)} hits + {len(part.shed)} shed excluded "
-                  f"by construction)")
-        return
-    if args.check_tokens and not recurrent:
-        mode, eps = parse_check_mode(args.check_tokens)
-        cont = serve_continuous(cfg, params, corpus, idx, wl, args,
-                                econf=econf, fleet_conf=fleet_conf)
+            out.linf = check_tokens(
+                miss_results, seq, args, "front-door miss requests",
+                f"continuous vs sequential; {len(part.hits)} hits + "
+                f"{len(part.shed)} shed excluded by construction")
+        return out
+    checked = bool(args.check_tokens) and not recurrent
+    if (args.sequential or recurrent) and not checked:
         seq = serve_sequential(cfg, params, corpus, idx, wl, args,
                                econf=econf)
-        mismatches = token_mismatches(
-            zip(cont, sorted(seq, key=lambda r: r.req_id)), mode, eps)
-        if mismatches:
-            raise SystemExit(f"token mismatch: {mismatches}")
-        what = "identical" if mode == "exact" else f"within tol {eps:g}"
-        print(f"\ntoken check: all {len(cont)} requests {what} "
-              f"(continuous vs sequential)")
-    elif args.sequential or recurrent:
-        serve_sequential(cfg, params, corpus, idx, wl, args, econf=econf)
-    else:
-        serve_continuous(cfg, params, corpus, idx, wl, args,
-                         econf=econf, fleet_conf=fleet_conf)
+        return ServeOutcome(cfg, sorted(seq, key=lambda r: r.req_id), [])
+    cont, rts = serve_continuous(cfg, params, corpus, idx, wl, args,
+                                 econf=econf, fleet_conf=fleet_conf)
+    out = ServeOutcome(cfg, cont, rts)
+    if checked:
+        seq = serve_sequential(cfg, params, corpus, idx, wl, args,
+                               econf=econf)
+        out.linf = check_tokens(cont, seq, args, "requests",
+                                "continuous vs sequential")
+    return out
 
 
 if __name__ == "__main__":

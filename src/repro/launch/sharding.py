@@ -53,7 +53,7 @@ def _heads_tile_cleanly(cfg: ModelConfig, msize: int) -> bool:
 
 def kv_heads_shardable(cfg: ModelConfig, tp: int) -> bool:
     """True when a ``tp``-way model axis splits attention into WHOLE heads:
-    the paged pool's ``(L, n_blocks, block, KV, hd)`` planes shard dim 3, so
+    the paged pool's ``(L, n_blocks, KV, block, hd)`` planes shard dim 2, so
     a KV head split *across* devices would tear a page's head tile apart
     (and break the per-shard kernel dispatch's head-local block tables)."""
     return (tp >= 1 and cfg.n_kv_heads % tp == 0 and cfg.n_heads % tp == 0)
@@ -110,10 +110,10 @@ def spec_summary(cfg: ModelConfig, mesh: Mesh, params_shape) -> str:
 
 
 def pool_kv_spec() -> P:
-    """Paged-pool partition spec: ``(L, n_blocks, block, KV, hd)`` shards
+    """Paged-pool partition spec: ``(L, n_blocks, KV, block, hd)`` shards
     whole KV heads over ``model``; block geometry stays replicated (block
     tables / slot mappings are identical on every shard)."""
-    return P(None, None, None, "model", None)
+    return P(None, None, "model", None, None)
 
 
 def serving_param_shardings(cfg: ModelConfig, params_shape, mesh: Mesh):

@@ -543,7 +543,7 @@ def paged_decode_step(cfg: ModelConfig, params, tokens, k_pages, v_pages,
     """One decode iteration straight against the paged pool (RAGCache's
     steady-state hot path: no dense (L, B, S, KV, hd) re-materialization).
 
-    k_pages/v_pages: the ``PagedKVStore`` buffers, (L, n_blocks, block, KV,
+    k_pages/v_pages: the ``PagedKVStore`` buffers, (L, n_blocks, KV, block,
     hd).  tables/counts/starts: (B, n_slots) per-request run descriptors
     (token-level slot mapping compressed to runs — see
     kernels/paged_attention.py for the contract).  write_blk/write_slot:
@@ -576,8 +576,9 @@ def paged_decode_step(cfg: ModelConfig, params, tokens, k_pages, v_pages,
         q, k, v = _qkv(cfg, p, h)                          # S == 1
         q = L.apply_rope(q, rope_pos, cfg.rope_theta)
         k = L.apply_rope(k, rope_pos, cfg.rope_theta)
-        kp = kp.at[li, write_blk, write_slot].set(k[:, 0].astype(kp.dtype))
-        vp = vp.at[li, write_blk, write_slot].set(v[:, 0].astype(vp.dtype))
+        # [li, blk, :, slot] -> (B, KV, hd): the index arrays' dims lead
+        kp = kp.at[li, write_blk, :, write_slot].set(k[:, 0].astype(kp.dtype))
+        vp = vp.at[li, write_blk, :, write_slot].set(v[:, 0].astype(vp.dtype))
         o = ops.paged_decode_attention(
             q[:, 0], kp, vp, tables, counts, starts, pos - 1, li, w,
             logit_cap=cfg.attn_logit_softcap, impl=attn_impl, mesh=mesh)
@@ -603,7 +604,7 @@ def paged_prefill_step(cfg: ModelConfig, params, tokens, k_pages, v_pages,
     tokens: (B, Sq) right-padded chunk token rows; row ``b`` holds
     ``q_len[b]`` valid tokens whose first sits at absolute position
     ``q_start[b]``.  k_pages/v_pages: the ``PagedKVStore`` buffers,
-    (L, n_blocks, block, KV, hd).  tables/counts/starts: (B, n_slots) run
+    (L, n_blocks, KV, block, hd).  tables/counts/starts: (B, n_slots) run
     descriptors covering the cached prefix PLUS this chunk's freshly
     allocated pages (counts include the chunk's own tokens — causal masking
     over absolute positions keeps later rows from seeing earlier garbage).
@@ -637,8 +638,9 @@ def paged_prefill_step(cfg: ModelConfig, params, tokens, k_pages, v_pages,
         q, k, v = _qkv(cfg, p, h)                          # (B, Sq, ., hd)
         q = L.apply_rope(q, positions, cfg.rope_theta)
         k = L.apply_rope(k, positions, cfg.rope_theta)
-        kp = kp.at[li, write_blk, write_slot].set(k.astype(kp.dtype))
-        vp = vp.at[li, write_blk, write_slot].set(v.astype(vp.dtype))
+        # [li, blk, :, slot] -> (B, Sq, KV, hd): the index arrays' dims lead
+        kp = kp.at[li, write_blk, :, write_slot].set(k.astype(kp.dtype))
+        vp = vp.at[li, write_blk, :, write_slot].set(v.astype(vp.dtype))
         o = ops.paged_prefill_attention(
             q.transpose(0, 2, 1, 3), kp, vp, tables, counts, starts,
             q_start, q_len, li, w, logit_cap=cfg.attn_logit_softcap,

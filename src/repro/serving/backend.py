@@ -7,9 +7,8 @@ Before this module the contract existed only by convention: ``PagedBackend``
 ``core/knowledge_tree.py::CacheBackend``'s duck-typed dispatch, and nothing
 would catch a fourth implementation drifting (a misspelled ``free_gpu`` only
 surfaces as a silently-unfreed tier).  ``Backend`` is that surface as a
-``typing.Protocol``; the tensor-parallel ``ShardedPagedBackend``
-(serving/runtime.py) is the fourth implementation of the now-explicit
-contract, and tests/test_backend_protocol.py holds all four to it.
+``typing.Protocol``, and tests/test_backend_protocol.py holds every
+implementation to it.
 
 Hop methods return the SECONDS the copy cost (measured wall time in the real
 backends, analytic transfer time in the simulator's); free methods return

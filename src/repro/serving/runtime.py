@@ -70,7 +70,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from jax.sharding import NamedSharding, PartitionSpec
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.core.controller import RAGController, effective_recompute
 from repro.core.knowledge_tree import (CacheBackend, EvictionError,
@@ -78,7 +78,8 @@ from repro.core.knowledge_tree import (CacheBackend, EvictionError,
 from repro.core.profiler import CostProfiler
 from repro.core.speculative import SpecState, SpeculativeController
 from repro.kvcache.paged import (DiskSegmentStore, OutOfBlocks, PagedKVStore,
-                                 PagedSegment, make_disk_store)
+                                 PagedSegment, gather_slots, make_disk_store,
+                                 scatter_slots)
 from repro.launch.mesh import make_serving_mesh
 from repro.launch.sharding import (assert_tp_compatible, pool_kv_spec,
                                    serving_param_shardings)
@@ -99,7 +100,14 @@ WAITING, RUNNING, FINISHED = "waiting", "running", "finished"
 class PagedBackend(CacheBackend):
     """Tree payloads are PagedSegments in the shared device store; the host
     tier holds dense numpy copies; the optional disk tier holds one mmap
-    file per node (``DiskSegmentStore``). Transfer seconds are measured."""
+    file per node (``DiskSegmentStore``). Transfer seconds are measured.
+
+    The store may be placed on one chip or KV-head-sharded over a TP mesh;
+    both hops then copy per device: demote (``swap_out``) pulls each
+    device's head slice once and reassembles the dense host copy, and
+    promote (``load``) hands the store host numpy, which it copies straight
+    to where the pool lives (``PagedKVStore._place_segment``) — never a
+    full replica on one device that the pool write would then reshard."""
 
     def __init__(self, store: PagedKVStore,
                  disk: Optional[DiskSegmentStore] = None):
@@ -108,16 +116,15 @@ class PagedBackend(CacheBackend):
 
     def swap_out(self, node):
         t0 = time.perf_counter()
-        k, v = self.store.gather(node.payload_gpu)
+        k, v = jax.device_get(self.store.gather(node.payload_gpu))
         node.payload_host = {"k": np.asarray(k), "v": np.asarray(v)}
         return time.perf_counter() - t0
 
     def load(self, node):
         t0 = time.perf_counter()
         try:
-            node.payload_gpu = self.store.put(
-                jnp.asarray(node.payload_host["k"]),
-                jnp.asarray(node.payload_host["v"]))
+            node.payload_gpu = self.store.put(node.payload_host["k"],
+                                              node.payload_host["v"])
         except OutOfBlocks as e:
             raise EvictionError(str(e))   # promote() degrades to recompute
         jax.block_until_ready(self.store.k)
@@ -144,40 +151,6 @@ class PagedBackend(CacheBackend):
         if node.payload_disk is not None:
             self.disk.delete(node.payload_disk)
         node.payload_disk = None
-
-
-class ShardedPagedBackend(PagedBackend):
-    """Tensor-parallel pool backend — the fourth implementation of the
-    ``serving/backend.py::Backend`` contract.
-
-    Same tier semantics as ``PagedBackend``, but the device tier is a
-    KV-head-sharded pool, so both hops batch their copies per mesh-axis
-    member instead of staging a replicated segment:
-
-      * demote (``swap_out``): ``device_get`` pulls each device's head slice
-        exactly once and reassembles the dense host copy;
-      * promote (``load``): the host segment enters ``store.put`` as numpy,
-        and the store's ``_shard_segment`` ``device_put``s it with the
-        pool's own KV-head sharding — one sub-copy per shard, never a full
-        replica that the pool write would immediately reshard.
-    """
-
-    def swap_out(self, node):
-        t0 = time.perf_counter()
-        k, v = self.store.gather(node.payload_gpu)
-        k, v = jax.device_get((k, v))
-        node.payload_host = {"k": np.asarray(k), "v": np.asarray(v)}
-        return time.perf_counter() - t0
-
-    def load(self, node):
-        t0 = time.perf_counter()
-        try:
-            node.payload_gpu = self.store.put(node.payload_host["k"],
-                                              node.payload_host["v"])
-        except OutOfBlocks as e:
-            raise EvictionError(str(e))   # promote() degrades to recompute
-        jax.block_until_ready(self.store.k)
-        return time.perf_counter() - t0
 
 
 @dataclasses.dataclass
@@ -311,14 +284,17 @@ class ContinuousRuntime:
         n_blocks: Optional[int] = None,
         reorder_window: int = 32,
         profiler: Optional[CostProfiler] = None,
+        devices: Optional[Sequence] = None,
         **legacy,
     ):
         # ``config=`` is the SOLE constructor API (serving/config.py): one
         # frozen EngineConfig carries the whole knob surface, and any
         # pre-PR 7 loose kwarg raises a TypeError naming the config field
         # that replaced it.  ``n_blocks`` / ``reorder_window`` /
-        # ``profiler`` stay explicit kwargs: they take test-only shapes or
-        # live objects that don't belong in a CLI-round-trip config.
+        # ``profiler`` / ``devices`` stay explicit kwargs: they take
+        # test-only shapes or live objects that don't belong in a
+        # CLI-round-trip config.  ``devices``: the chips this replica owns
+        # (``mesh.tp`` of them; None = the first ``tp`` visible devices).
         reject_legacy_kwargs("ContinuousRuntime", legacy, EngineConfig)
         config = config if config is not None else EngineConfig()
         gpu_cache_bytes = config.gpu_cache_bytes
@@ -371,10 +347,10 @@ class ContinuousRuntime:
         self.index = index
         self.top_k = top_k
         self.search_time_scale = search_time_scale
-        # ---- tensor parallelism (one replica spanning tp devices) --------
+        # ---- placement: one chip, or tp chips (tensor parallelism) -------
         # Params shard per launch/sharding.py::serving_param_shardings
         # (Megatron column rules; the two row matrices replicate — see the
-        # deterministic-TP note there); the pool's (L, n_blocks, block, KV,
+        # deterministic-TP note there); the pool's (L, n_blocks, KV, block,
         # hd) planes shard whole KV heads over the "model" axis; block
         # tables / slot mappings / run tables stay replicated (they are
         # head-independent), so every scheduler/tree decision is identical
@@ -383,18 +359,26 @@ class ContinuousRuntime:
         # bit-identical --check-tokens contract across mesh sizes.
         self.mesh_cfg = mesh or MeshConfig()
         self._mesh = None
-        self._kv_sharding = None
-        # CAG preloads compute each doc's KV through the single-device dense
-        # prefill on the PRE-shard params (bit-identical to the sequential
-        # oracle by construction); the sharded pool re-shards host copies on
-        # promote, so the preloaded tier bytes work at any tp
-        self._preload_params = params
+        self._kv_sharding = None          # set only under TP
+        pool_sharding = None              # None = the default device
         if self.mesh_cfg.tp > 1:
             assert_tp_compatible(cfg, self.mesh_cfg.tp)
-            self._mesh = make_serving_mesh(self.mesh_cfg.tp)
+            self._mesh = make_serving_mesh(self.mesh_cfg.tp, devices)
             params = jax.device_put(
                 params, serving_param_shardings(cfg, params, self._mesh))
             self._kv_sharding = NamedSharding(self._mesh, pool_kv_spec())
+            pool_sharding = self._kv_sharding
+        elif devices is not None:
+            pool_sharding = SingleDeviceSharding(devices[0])
+            params = jax.device_put(params, pool_sharding)
+        # CAG preloads compute each doc's KV through the single-device dense
+        # prefill (bit-identical to the sequential oracle by construction);
+        # the pool re-places host copies on promote, so the preloaded tier
+        # bytes work at any tp.  Only CAG keeps this unsharded copy.
+        self._preload_params = None
+        if self.mode == "cag":
+            one = devices[0] if devices is not None else jax.devices()[0]
+            self._preload_params = jax.device_put(params, one)
         self.params = params
         kv_bytes = (2 * cfg.n_layers * cfg.n_kv_heads * cfg.hd
                     * jnp.dtype(cfg.jdtype).itemsize)
@@ -404,7 +388,7 @@ class ContinuousRuntime:
         self.store = PagedKVStore(cfg.n_layers, n_blocks, block_size,
                                   cfg.n_kv_heads, cfg.hd,
                                   dtype=cfg.jdtype, device=True,
-                                  kv_sharding=self._kv_sharding)
+                                  sharding=pool_sharding)
         self._scratch_block = self.store.pool.alloc(1)[0]  # dummy-row sink
         self.disk = make_disk_store(disk_cache_dir, disk_cache_bytes)
         self.tree = KnowledgeTree(
@@ -414,8 +398,7 @@ class ContinuousRuntime:
             profiler=profiler or CostProfiler.from_fn(
                 lambda a, b: 1e-4 * b + 2e-8 * b * (a + b),
                 (0, 64, 256, 1024), (1, 32, 128, 512, 1024)),
-            backend=(ShardedPagedBackend if self._kv_sharding is not None
-                     else PagedBackend)(self.store, self.disk),
+            backend=PagedBackend(self.store, self.disk),
             bytes_per_token=max(kv_bytes, 1),
         )
         self.controller = RAGController(self.tree)
@@ -448,6 +431,7 @@ class ContinuousRuntime:
                                  wb, ws, attn_impl=_impl, mesh=_tp_mesh),
             donate_argnums=(9, 10), **self._decode_jit_kw())
         self._decode_fn = None        # built in serve() once n_slots is known
+        self.prefill_shapes = set()   # (rows, chunk bucket, table) run
         self._n_slots = 0
         self._n_tbl = 0               # run-table width (paged mode)
         # event loop
@@ -1103,6 +1087,7 @@ class ContinuousRuntime:
         starts = np.zeros((B, T), np.int32)
         q_start = np.zeros((B,), np.int32)
         q_len = np.zeros((B,), np.int32)
+        self.prefill_shapes.add((B, Sq, T))
         for i, (job, t, wb, ws, qs, tb, cn, st_, n) in enumerate(rows):
             toks[i, :n] = t
             wblk[i, :n] = wb
@@ -1502,6 +1487,27 @@ class ContinuousRuntime:
                 self.params, *args, self.store.k, self.store.v)
         jax.block_until_ready(self.store.k)
 
+    def compiled_steps(self) -> Dict[str, str]:
+        """HLO text of the paged programs this runtime has run: ``decode``
+        and one ``prefill BxSq`` per ragged-batch bucket — what a caller
+        inspects to see what executes (e.g. that the Pallas kernels are
+        in it).  Recompiling hits JAX's compile caches."""
+        if self.attn != "paged":
+            return {}
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+        out = {}
+        with self._trace_ctx():
+            if self._decode_fn is not None:
+                out["decode"] = self._decode_fn.lower(
+                    self.params, *self._paged_decode_args([]),
+                    self.store.k, self.store.v).compile().as_text()
+            for B, Sq, T in sorted(self.prefill_shapes):
+                out[f"prefill {B}x{Sq}"] = self._paged_prefill_fn.lower(
+                    self.params, i32(B, Sq), i32(B, T), i32(B, T),
+                    i32(B, T), i32(B), i32(B), i32(B, Sq), i32(B, Sq),
+                    self.store.k, self.store.v).compile().as_text()
+        return out
+
     def _paged_decode_args(self, batch):
         """Pack the run tables for one paged decode iteration.  Contract
         (kernels/paged_attention.py): the slot mapping is a list of runs,
@@ -1545,8 +1551,8 @@ class ContinuousRuntime:
             # of request b lives at (blk_map[b, p], slot_map[b, p]), so the
             # gathered dense sequence is hole-free even when shared tree
             # segments end mid-block — sharing needs no block alignment
-            k = k_pages[:, blk_map, slot_map]       # (L, B, S, KV, hd)
-            v = v_pages[:, blk_map, slot_map]
+            k = gather_slots(k_pages, blk_map, slot_map)  # (L, B, S, KV, hd)
+            v = gather_slots(v_pages, blk_map, slot_map)
             logits, new = M.decode_step(cfg, params, toks,
                                         {"k": k, "v": v}, lengths + 1)
             bidx = jnp.arange(B)
@@ -1554,8 +1560,8 @@ class ContinuousRuntime:
             newv = new["v"][:, bidx, lengths]
             blk = blk_map[bidx, lengths]
             slot = slot_map[bidx, lengths]
-            k_pages = k_pages.at[:, blk, slot].set(newk.astype(k_pages.dtype))
-            v_pages = v_pages.at[:, blk, slot].set(newv.astype(v_pages.dtype))
+            k_pages = scatter_slots(k_pages, blk, slot, newk)
+            v_pages = scatter_slots(v_pages, blk, slot, newv)
             return jnp.argmax(logits[:, -1], axis=-1), k_pages, v_pages
 
         self._decode_fn = jax.jit(step, donate_argnums=(5, 6),

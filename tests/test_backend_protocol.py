@@ -1,9 +1,9 @@
 """Conformance tests for the ``serving/backend.py::Backend`` protocol.
 
 The tier-hop contract used to exist only by convention across three
-backends; this file holds all FOUR implementations (the duck-typed
-``CacheBackend`` base, ``PagedBackend``, the tensor-parallel
-``ShardedPagedBackend``, ``_JaxBackend``, ``_SimBackend``) to the explicit
+backends; this file holds every implementation (the duck-typed
+``CacheBackend`` base, ``PagedBackend``, ``_JaxBackend``,
+``_SimBackend``) to the explicit
 Protocol, and exercises the base implementation's tier moves live so the
 generic ``demote_copy``/``promote_copy``/``free_tier`` dispatch stays
 wired to the named hops.
@@ -16,8 +16,7 @@ from repro.core.knowledge_tree import CacheBackend, Node  # noqa: E402
 from repro.core.profiler import A10G_MISTRAL_7B  # noqa: E402
 from repro.serving.backend import Backend, conforms  # noqa: E402
 from repro.serving.engine import _JaxBackend  # noqa: E402
-from repro.serving.runtime import (PagedBackend,  # noqa: E402
-                                   ShardedPagedBackend)
+from repro.serving.runtime import PagedBackend  # noqa: E402
 from repro.serving.simulator import _SimBackend  # noqa: E402
 
 
@@ -28,10 +27,9 @@ def _node():
 @pytest.mark.parametrize("make", [
     CacheBackend,
     lambda: PagedBackend(store=None, disk=None),
-    lambda: ShardedPagedBackend(store=None, disk=None),
     _JaxBackend,
     lambda: _SimBackend(A10G_MISTRAL_7B),
-], ids=["base", "paged", "sharded_paged", "jax", "sim"])
+], ids=["base", "paged", "jax", "sim"])
 def test_backend_conforms(make):
     """Every implementation satisfies the Protocol (method presence)."""
     assert conforms(make())

@@ -16,14 +16,7 @@ same commit, and extend the blocker note in pyproject.toml.  To shrink
 it (the goal): ``ruff format <file>``, then delete the entry from both.
 """
 import pathlib
-
-import pytest
-
-try:
-    import tomllib                      # py311+
-except ImportError:                     # py310 fast lane
-    tomli = pytest.importorskip("tomli")
-    tomllib = tomli
+import tomllib
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 

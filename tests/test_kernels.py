@@ -74,8 +74,8 @@ def test_prefix_attention_matches_model_flash():
 def test_paged_attention_sweep(B, H, KV, hd, page, npages, nslots, dtype):
     k1, k2, k3, k4, k5 = jax.random.split(KEY, 5)
     q = jax.random.normal(k1, (B, H, hd), dtype)
-    kp = jax.random.normal(k2, (npages, page, KV, hd), dtype)
-    vp = jax.random.normal(k3, (npages, page, KV, hd), dtype)
+    kp = jax.random.normal(k2, (npages, KV, page, hd), dtype)
+    vp = jax.random.normal(k3, (npages, KV, page, hd), dtype)
     bt = jax.random.randint(k4, (B, nslots), 0, npages)
     maxlen = page * nslots
     lengths = jax.random.randint(k5, (B,), 1, maxlen + 1)
@@ -97,11 +97,12 @@ def test_paged_attention_respects_block_table_permutation():
     lengths = jnp.asarray([20], jnp.int32)
 
     def place(order):
-        kp = jnp.zeros((npages, page, KV, hd))
-        vp = jnp.zeros((npages, page, KV, hd))
+        kp = jnp.zeros((npages, KV, page, hd))
+        vp = jnp.zeros((npages, KV, page, hd))
         for i, pg in enumerate(order):
-            kp = kp.at[pg].set(kv[i * page:(i + 1) * page])
-            vp = vp.at[pg].set(kv[i * page:(i + 1) * page] * 0.5)
+            tile = kv[i * page:(i + 1) * page].swapaxes(0, 1)
+            kp = kp.at[pg].set(tile)
+            vp = vp.at[pg].set(tile * 0.5)
         return kp, vp, jnp.asarray([order], jnp.int32)
 
     o1 = ops.paged_attention(q, *place([0, 1, 2]), lengths, interpret=True)

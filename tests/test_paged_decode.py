@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kvcache.paged import gather_slots
 from repro.models import layers as L
 from repro.models import model as M
 
@@ -23,18 +24,18 @@ def _runs_to_dense(kp, vp, tables, counts, layer):
     """Gather the logical sequences out of the page planes: (B, Smax, KV, hd)
     dense caches + (B,) lengths, padding rows to the longest request."""
     B, n_slots = tables.shape
-    page = kp.shape[2]
+    KV, page, hd = kp.shape[2:]
     lengths = np.asarray(counts.sum(axis=1))
     smax = max(int(lengths.max()), 1)
-    KV, hd = kp.shape[3], kp.shape[4]
     dk = np.zeros((B, smax, KV, hd), np.asarray(kp).dtype)
     dv = np.zeros_like(dk)
     for b in range(B):
         t = 0
         for j in range(n_slots):
             c = int(counts[b, j])
-            dk[b, t:t + c] = np.asarray(kp)[layer, int(tables[b, j]), :c]
-            dv[b, t:t + c] = np.asarray(vp)[layer, int(tables[b, j]), :c]
+            pid = int(tables[b, j])
+            dk[b, t:t + c] = np.asarray(kp)[layer, pid, :, :c].swapaxes(0, 1)
+            dv[b, t:t + c] = np.asarray(vp)[layer, pid, :, :c].swapaxes(0, 1)
             t += c
     return jnp.asarray(dk), jnp.asarray(dv), jnp.asarray(lengths, jnp.int32)
 
@@ -42,8 +43,8 @@ def _runs_to_dense(kp, vp, tables, counts, layer):
 def _random_case(key, B, H, KV, hd, page, n_pages, n_slots, dtype=jnp.float32):
     k1, k2, k3, k4, k5 = jax.random.split(key, 5)
     q = jax.random.normal(k1, (B, H, hd), dtype)
-    kp = jax.random.normal(k2, (3, n_pages, page, KV, hd), dtype)
-    vp = jax.random.normal(k3, (3, n_pages, page, KV, hd), dtype)
+    kp = jax.random.normal(k2, (3, n_pages, KV, page, hd), dtype)
+    vp = jax.random.normal(k3, (3, n_pages, KV, page, hd), dtype)
     tables = jax.random.randint(k4, (B, n_slots), 0, n_pages)
     counts = jax.random.randint(k5, (B, n_slots), 0, page + 1)
     starts = jnp.concatenate([jnp.zeros((B, 1), jnp.int32),
@@ -153,8 +154,8 @@ def test_single_layer_wrapper_matches_legacy_reference():
     k1, k2, k3, k4 = jax.random.split(KEY, 4)
     B, H, KV, hd, page, n_pages, n_slots = 2, 8, 2, 64, 16, 8, 3
     q = jax.random.normal(k1, (B, H, hd))
-    kp = jax.random.normal(k2, (n_pages, page, KV, hd))
-    vp = jax.random.normal(k3, (n_pages, page, KV, hd))
+    kp = jax.random.normal(k2, (n_pages, KV, page, hd))
+    vp = jax.random.normal(k3, (n_pages, KV, page, hd))
     bt = jax.random.randint(k4, (B, n_slots), 0, n_pages)
     lengths = jnp.asarray([1, 37], jnp.int32)
     out = ops.paged_attention(q, kp, vp, bt, lengths, interpret=True)
@@ -173,11 +174,12 @@ def _check_permutation_invariance(perm, length):
     q = jax.random.normal(k1, (s["B"], s["H"], s["hd"]))
     kv = jax.random.normal(k2, (s["n_slots"] * s["page"], s["KV"], s["hd"]))
     order = list(perm)[:s["n_slots"]]
-    kp = jnp.zeros((s["n_pages"], s["page"], s["KV"], s["hd"]))
+    kp = jnp.zeros((s["n_pages"], s["KV"], s["page"], s["hd"]))
     vp = jnp.zeros_like(kp)
     for i, pid in enumerate(order):
-        kp = kp.at[pid].set(kv[i * s["page"]:(i + 1) * s["page"]])
-        vp = vp.at[pid].set(kv[i * s["page"]:(i + 1) * s["page"]] * 0.5)
+        tile = kv[i * s["page"]:(i + 1) * s["page"]].swapaxes(0, 1)
+        kp = kp.at[pid].set(tile)
+        vp = vp.at[pid].set(tile * 0.5)
     bt = jnp.asarray([order], jnp.int32)
     lengths = jnp.asarray([length], jnp.int32)
     kern = ops.paged_attention(q, kp, vp, bt, lengths, interpret=True)
@@ -255,7 +257,7 @@ def test_paged_decode_step_matches_decode_step(serving_setup):
                                                                None, None]
     cache = {"k": k * mask, "v": v * mask}
     # scatter the dense caches into paged planes with unaligned runs
-    kp = jnp.zeros((cfg.n_layers, n_blocks, bs, cfg.n_kv_heads, cfg.hd))
+    kp = jnp.zeros((cfg.n_layers, n_blocks, cfg.n_kv_heads, bs, cfg.hd))
     vp = jnp.zeros_like(kp)
     free = list(rng.permutation(n_blocks - 1) + 1)   # block 0 = scratch
     T = 6
@@ -273,8 +275,10 @@ def test_paged_decode_step_matches_decode_step(serving_setup):
         for j, c in enumerate(run_lens[b]):
             blk = free.pop()
             take = min(c, lens[b] - t)             # last run: slot reserved
-            kp = kp.at[:, blk, :take].set(cache["k"][:, b, t:t + take])
-            vp = vp.at[:, blk, :take].set(cache["v"][:, b, t:t + take])
+            kp = kp.at[:, blk, :, :take].set(
+                cache["k"][:, b, t:t + take].swapaxes(1, 2))
+            vp = vp.at[:, blk, :, :take].set(
+                cache["v"][:, b, t:t + take].swapaxes(1, 2))
             tables[b, j] = blk
             starts[b, j] = t
             t += take
@@ -303,9 +307,11 @@ def test_paged_decode_step_matches_decode_step(serving_setup):
     # bf16 tolerance since layer>0 projections see ULP-shifted activations
     bidx = jnp.arange(B)
     new_k = want_cache["k"][:, bidx, pos - 1]
-    np.testing.assert_allclose(np.asarray(kp2[:, wblk, wslot], np.float32),
-                               np.asarray(new_k, np.float32), atol=2e-2)
-    assert np.abs(np.asarray(vp2[:, wblk, wslot], np.float32)).max() > 0
+    np.testing.assert_allclose(
+        np.asarray(gather_slots(kp2, wblk, wslot), np.float32),
+        np.asarray(new_k, np.float32), atol=2e-2)
+    assert np.abs(np.asarray(gather_slots(vp2, wblk, wslot),
+                             np.float32)).max() > 0
 
 
 def test_runtime_paged_tokens_match_dense_and_tables_pack_runs(serving_setup):

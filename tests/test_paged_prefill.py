@@ -31,8 +31,8 @@ def _random_case(key, B, H, KV, hd, page, n_pages, n_slots,
     request — the mid-prefill shape: everything before q_start is cached."""
     k1, k2, k3, k4, k5 = jax.random.split(key, 5)
     q = jax.random.normal(k1, (B, H, Sq, hd), dtype)
-    kp = jax.random.normal(k2, (3, n_pages, page, KV, hd), dtype)
-    vp = jax.random.normal(k3, (3, n_pages, page, KV, hd), dtype)
+    kp = jax.random.normal(k2, (3, n_pages, KV, page, hd), dtype)
+    vp = jax.random.normal(k3, (3, n_pages, KV, page, hd), dtype)
     tables = jax.random.randint(k4, (B, n_slots), 0, n_pages)
     counts = jax.random.randint(k5, (B, n_slots), 0, page + 1)
     starts = jnp.concatenate([jnp.zeros((B, 1), jnp.int32),
@@ -54,13 +54,15 @@ def _scatter_sequence(key, T, KV, hd, page, n_pages, order=None, layer=1):
     nb = -(-T // page)
     if order is None:
         order = list(range(1, nb + 1))
-    kp = jax.random.normal(k3, (3, n_pages, page, KV, hd))
+    kp = jax.random.normal(k3, (3, n_pages, KV, page, hd))
     vp = kp * -0.7 + 1.3
     counts = np.zeros(nb, np.int32)
     for i, pid in enumerate(order[:nb]):
         c = min(page, T - i * page)
-        kp = kp.at[layer, pid, :c].set(kseq[i * page:i * page + c])
-        vp = vp.at[layer, pid, :c].set(vseq[i * page:i * page + c])
+        kp = kp.at[layer, pid, :, :c].set(
+            kseq[i * page:i * page + c].swapaxes(0, 1))
+        vp = vp.at[layer, pid, :, :c].set(
+            vseq[i * page:i * page + c].swapaxes(0, 1))
         counts[i] = c
     tables = jnp.asarray([order[:nb]], jnp.int32)
     counts = jnp.asarray(counts[None])
@@ -143,7 +145,7 @@ def test_midblock_unaligned_cached_tails():
     shift every later absolute position — the exact case a page-aligned
     assumption breaks.  Gather the runs densely and compare."""
     page, KV, H, hd = 8, 2, 4, 32
-    kp = jax.random.normal(jax.random.fold_in(KEY, 1), (3, 16, page, KV, hd))
+    kp = jax.random.normal(jax.random.fold_in(KEY, 1), (3, 16, KV, page, hd))
     vp = jax.random.normal(jax.random.fold_in(KEY, 2), kp.shape)
     tables = jnp.asarray([[3, 7, 1, 9], [5, 5, 0, 0]], jnp.int32)
     counts = jnp.asarray([[5, 3, 8, 2], [8, 6, 0, 0]], jnp.int32)
@@ -163,8 +165,9 @@ def test_midblock_unaligned_cached_tails():
         dv = np.zeros_like(dk)
         for j in range(tables.shape[1]):
             c, s0 = int(counts[b, j]), int(starts[b, j])
-            dk[s0:s0 + c] = np.asarray(kp)[layer, int(tables[b, j]), :c]
-            dv[s0:s0 + c] = np.asarray(vp)[layer, int(tables[b, j]), :c]
+            pid = int(tables[b, j])
+            dk[s0:s0 + c] = np.asarray(kp)[layer, pid, :, :c].swapaxes(0, 1)
+            dv[s0:s0 + c] = np.asarray(vp)[layer, pid, :, :c].swapaxes(0, 1)
         n = int(q_len[b])
         dense = ref.reference_prefix_attention(
             q[b:b + 1, :, :n], jnp.asarray(dk.transpose(1, 0, 2))[None],
@@ -414,7 +417,7 @@ def test_paged_prefill_step_matches_dense_prefill(serving_setup):
 
     wblk, wslot, tables, counts, starts = _alloc_plan(
         cfg, n_tokens, bs, n_blocks, rng)
-    kp = jnp.zeros((cfg.n_layers, n_blocks, bs, cfg.n_kv_heads, cfg.hd),
+    kp = jnp.zeros((cfg.n_layers, n_blocks, cfg.n_kv_heads, bs, cfg.hd),
                    cfg.jdtype)
     vp = jnp.zeros_like(kp)
     got, kp1, vp1 = M.paged_prefill_step(

@@ -150,3 +150,49 @@ def test_main_workload_knob_flags(monkeypatch, capsys):
                     ["--sequential", "--zipf-s", "1.5", "--drift", "0.3",
                      "--n-phases", "4", "--output-len-mean", "2"])
     assert "[sequential] served 4 requests" in out
+
+
+def test_main_returns_what_it_served(capsys):
+    """main() hands back the config, results, runtimes and the oracle
+    L-inf, and prints the L-inf beside the verdict."""
+    out = serve.main(TINY + ["--check-tokens"])
+    text = capsys.readouterr().out
+    assert out.cfg.name == "qwen2-reduced"
+    assert [r.req_id for r in out.results] == [0, 1, 2, 3]
+    assert len(out.runtimes) == 1
+    assert out.linf is not None and out.linf >= 0.0
+    assert "first-token logit L-inf vs the sequential oracle" in text
+    assert "4/4 requests with identical tokens" in text
+
+
+def test_published_flag_selects_the_published_config():
+    from repro.configs import get_config, get_reduced
+
+    parse = serve.build_parser().parse_args
+    assert serve.model_config(parse([])) == get_reduced("qwen2-0.5b")
+    assert serve.model_config(parse(["--published"])) == \
+        get_config("qwen2-0.5b")
+
+
+def test_compile_cache_dir(monkeypatch):
+    """The compile cache follows JAX_COMPILATION_CACHE_DIR when it is set
+    (JAX reads it; nothing is overridden) and is the checkout's fixed
+    .jax_cache otherwise."""
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", "/elsewhere")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert serve.setup_compile_cache() == "/elsewhere"
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert serve.setup_compile_cache() == str(serve.REPO_ROOT
+                                                  / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_replica_devices_wrap_and_refuse_oversized_tp():
+    devs = jax.devices()
+    assert serve.replica_devices(0, 1) == [devs[0]]
+    assert serve.replica_devices(len(devs), 1) == [devs[0]]
+    with pytest.raises(ValueError, match="visible"):
+        serve.replica_devices(0, len(devs) + 1)

@@ -27,7 +27,9 @@ SCRIPT = textwrap.dedent("""
         "decode_32k": dict(kind="decode", seq=128, batch=8),
         "long_500k": dict(kind="decode", seq=256, batch=1),
     }
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     out = {}
     for arch in ["qwen2-0.5b", "mixtral-8x7b", "xlstm-1.3b", "hymba-1.5b"]:
         cfg = get_reduced(arch)

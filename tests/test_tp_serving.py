@@ -138,9 +138,27 @@ def test_tp2_chunk_reuse_tolerance(monkeypatch, capsys):
 @need4
 def test_tp_with_paged_disk_tiers(monkeypatch, capsys):
     """Sharded pool + tiny GPU tier: demotions/promotions run through
-    ShardedPagedBackend's per-shard copies and tokens stay identical."""
+    PagedBackend's per-shard copies and tokens stay identical."""
     out = _run_main(monkeypatch, capsys,
                     ["--tp", "2", "--check-tokens",
                      "--gpu-cache-bytes", str(48 * 2**10),
                      "--disk-cache-bytes", str(8 * 2**20)])
     assert "token check: all 4 requests identical" in out
+
+
+@multidevice
+@need4
+def test_replicas_own_their_devices():
+    """--replicas 4 on 4 devices: replica i's params and paged pool live on
+    device i alone (and on 2 devices at tp=2: replica i owns [2i, 2i+2))."""
+    args = serve.build_parser().parse_args(TINY)
+    cfg, params, corpus, idx, _, _ = serve.make_setup(args)
+    devs = jax.devices()
+    for tp, n in ((1, 4), (2, 2)):
+        args.tp = tp
+        rts = serve.make_runtimes(cfg, params, corpus, idx, args, n)
+        for i, rt in enumerate(rts):
+            own = set(devs[i * tp:(i + 1) * tp])
+            placed = set().union(*(x.devices()
+                                   for x in jax.tree.leaves(rt.params)))
+            assert placed == own and rt.store.k.devices() == own
