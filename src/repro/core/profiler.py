@@ -3,10 +3,9 @@ interpolation — Algorithm 1 lines 6–9 of the paper.
 
 PGDSF needs the *per-non-cached-token* compute cost of a document given how
 much of its prefix was cached.  RAGCache profiles the LLM offline over a grid
-of (alpha, beta) and interpolates.  Two sources feed the same table format:
-
-  * measured: timing the real JAX model on this host (tiny models), and
-  * analytic: a hardware profile (A10G / H800 / TPU v5e) for the simulator.
+of (alpha, beta) and interpolates.  The table comes from a cost function
+(``CostProfiler.from_fn``) or an analytic hardware profile (A10G / H800 /
+TPU v5e, ``from_profile``) for the simulator.
 """
 from __future__ import annotations
 
@@ -182,18 +181,3 @@ class CostProfiler:
         t_h = T[(al, bh)] + ta * (T[(ah, bh)] - T[(al, bh)])
         return max(t_l + tb * (t_h - t_l), 0.0)
 
-
-def measure_profiler(prefill_fn: Callable[[int, int], float],
-                     alphas: Sequence[int], betas: Sequence[int],
-                     repeats: int = 2) -> CostProfiler:
-    """Build a profiler by timing a real prefill function (wall clock)."""
-    import time
-    tbl = {}
-    for a in alphas:
-        for b in betas:
-            prefill_fn(a, b)  # warm-up / compile
-            t0 = time.perf_counter()
-            for _ in range(repeats):
-                prefill_fn(a, b)
-            tbl[(a, b)] = (time.perf_counter() - t0) / repeats
-    return CostProfiler(alphas, betas, tbl)

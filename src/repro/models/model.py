@@ -577,20 +577,27 @@ def paged_decode_step(cfg: ModelConfig, params, tokens, k_pages, v_pages,
         q = L.apply_rope(q, rope_pos, cfg.rope_theta)
         k = L.apply_rope(k, rope_pos, cfg.rope_theta)
         # [li, blk, :, slot] -> (B, KV, hd): the index arrays' dims lead
-        kp = kp.at[li, write_blk, :, write_slot].set(k[:, 0].astype(kp.dtype))
-        vp = vp.at[li, write_blk, :, write_slot].set(v[:, 0].astype(vp.dtype))
-        o = ops.paged_decode_attention(
-            q[:, 0], kp, vp, tables, counts, starts, pos - 1, li, w,
-            logit_cap=cfg.attn_logit_softcap, impl=attn_impl, mesh=mesh)
+        with jax.named_scope("kv_write"):
+            kp = kp.at[li, write_blk, :, write_slot].set(
+                k[:, 0].astype(kp.dtype))
+            vp = vp.at[li, write_blk, :, write_slot].set(
+                v[:, 0].astype(vp.dtype))
+        with jax.named_scope("attn"):
+            o = ops.paged_decode_attention(
+                q[:, 0], kp, vp, tables, counts, starts, pos - 1, li, w,
+                logit_cap=cfg.attn_logit_softcap, impl=attn_impl, mesh=mesh)
         x = x + L.dense_rowsum(o.reshape(B, 1, -1), p["wo"])
-        h2 = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-        x = x + _ffn(cfg, p, h2)
+        with jax.named_scope("mlp"):
+            h2 = L.rms_norm(x, p["ln2"], cfg.norm_eps)
+            x = x + _ffn(cfg, p, h2)
         return (x, kp, vp), None
 
     (x, k_pages, v_pages), _ = lax.scan(
         body, (x, k_pages, v_pages),
         (params["blocks"], windows, jnp.arange(cfg.n_layers)))
-    return lm_logits(cfg, params, x), k_pages, v_pages
+    with jax.named_scope("logits"):
+        logits = lm_logits(cfg, params, x)
+    return logits, k_pages, v_pages
 
 
 def paged_prefill_step(cfg: ModelConfig, params, tokens, k_pages, v_pages,
@@ -639,16 +646,19 @@ def paged_prefill_step(cfg: ModelConfig, params, tokens, k_pages, v_pages,
         q = L.apply_rope(q, positions, cfg.rope_theta)
         k = L.apply_rope(k, positions, cfg.rope_theta)
         # [li, blk, :, slot] -> (B, Sq, KV, hd): the index arrays' dims lead
-        kp = kp.at[li, write_blk, :, write_slot].set(k.astype(kp.dtype))
-        vp = vp.at[li, write_blk, :, write_slot].set(v.astype(vp.dtype))
-        o = ops.paged_prefill_attention(
-            q.transpose(0, 2, 1, 3), kp, vp, tables, counts, starts,
-            q_start, q_len, li, w, logit_cap=cfg.attn_logit_softcap,
-            impl=attn_impl, mesh=mesh)
+        with jax.named_scope("kv_write"):
+            kp = kp.at[li, write_blk, :, write_slot].set(k.astype(kp.dtype))
+            vp = vp.at[li, write_blk, :, write_slot].set(v.astype(vp.dtype))
+        with jax.named_scope("attn"):
+            o = ops.paged_prefill_attention(
+                q.transpose(0, 2, 1, 3), kp, vp, tables, counts, starts,
+                q_start, q_len, li, w, logit_cap=cfg.attn_logit_softcap,
+                impl=attn_impl, mesh=mesh)
         x = x + L.dense_rowsum(o.transpose(0, 2, 1, 3).reshape(B, Sq, -1),
                                p["wo"])
-        h2 = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-        x = x + _ffn(cfg, p, h2)
+        with jax.named_scope("mlp"):
+            h2 = L.rms_norm(x, p["ln2"], cfg.norm_eps)
+            x = x + _ffn(cfg, p, h2)
         return (x, kp, vp), None
 
     (x, k_pages, v_pages), _ = lax.scan(
@@ -656,7 +666,9 @@ def paged_prefill_step(cfg: ModelConfig, params, tokens, k_pages, v_pages,
         (params["blocks"], windows, jnp.arange(cfg.n_layers)))
     last = jnp.clip(q_len - 1, 0, Sq - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)
-    return lm_logits(cfg, params, x_last), k_pages, v_pages
+    with jax.named_scope("logits"):
+        logits = lm_logits(cfg, params, x_last)
+    return logits, k_pages, v_pages
 
 
 def decode_step(cfg: ModelConfig, params, tokens, cache, pos):

@@ -16,13 +16,24 @@ routing accounting, aggregated into per-replica occupancy / hit-token
 tiers / routed-vs-escaped counts and cross-replica TTFT percentiles
 computed over the POOLED per-request timelines (exact, not a mean of
 per-replica percentiles).
+
+Two clocks: timelines, and so every TTFT / TPOT / queueing / search figure,
+run on the runtime's VIRTUAL clock (engine iterations advance it by their
+measured time, retrieval by max(measured, analytic), idle gaps skipped).
+``ServingMetrics.span`` is on the wall clock: it opens a profiler
+annotation under the span's bare name (ids become stats, so a trace groups
+by name) and adds the span's ``time.perf_counter`` seconds to
+``host_seconds[name]``.  A span around a span counts its child's time too.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import time
 from typing import Dict, List, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -102,12 +113,37 @@ def percentiles(xs: List[float]) -> Dict[str, float]:
     }
 
 
+class _Span:
+    """``with metrics.span(name, **ids) as sp``: a profiler annotation plus
+    the span's wall seconds, kept in ``sp.seconds`` and added to
+    ``host_seconds[name]``."""
+    __slots__ = ("_total", "_name", "_ann", "_t0", "seconds")
+
+    def __init__(self, total: Dict[str, float], name: str, ids: dict):
+        self._total, self._name = total, name
+        self._ann = TraceAnnotation(name, **ids)
+        self.seconds = 0.0
+
+    def __enter__(self) -> "_Span":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = time.perf_counter() - self._t0
+        self._total[self._name] += self.seconds
+        self._ann.__exit__(*exc)
+
+
 class ServingMetrics:
     """Aggregator owned by the runtime; the benchmark and launch driver read
     ``summary()`` / ``format_report()``."""
 
     def __init__(self):
         self.timelines: Dict[int, RequestTimeline] = {}
+        # wall seconds inside each named span (see ``span``), inclusive of
+        # the spans it encloses
+        self.host_seconds: Dict[str, float] = collections.Counter()
         # per engine iteration: ("prefill", 1) or ("decode", batch_size)
         self.iterations: List[tuple] = []
         self.wasted_prefills = 0
@@ -133,6 +169,11 @@ class ServingMetrics:
         self.exact_chunk_hits = 0      # docs reused bit-identically
         self.reloc_chunk_hits = 0      # docs reused at a new position
         self.reloc_recompute_tokens = 0   # boundary tokens recomputed
+
+    def span(self, name: str, **ids) -> _Span:
+        """Time a block of host work under ``name``; ``ids`` (a request id,
+        an iteration number) ride along as trace stats, never in the name."""
+        return _Span(self.host_seconds, name, ids)
 
     def record_prefill_batch(self, n_chunks: int, n_tokens: int) -> None:
         self.prefill_batches.append((n_chunks, n_tokens))
@@ -205,6 +246,7 @@ class ServingMetrics:
             "disk_prefetch_bytes": self.disk_prefetch_bytes,
             "doc_hit_rate": (sum(t.hit_docs for t in done)
                              / max(sum(t.n_docs for t in done), 1)),
+            "host_seconds": dict(self.host_seconds),
         }
 
     def format_report(self) -> str:
@@ -216,11 +258,13 @@ class ServingMetrics:
 
         lines = [
             f"completed requests      : {s['completed']}",
-            f"TTFT (ms)               : {ms(s['ttft'])}",
-            f"TPOT (ms)               : {ms(s['tpot'])}",
-            f"queueing (ms)           : {ms(s['queueing'])}",
-            f"search (ms)             : {ms(s['search'])}",
-            f"non-overlapped search   : {ms(s['non_overlapped_search'])}",
+            "runtime clock (virtual: iterations at their measured time, "
+            "idle gaps skipped; not wall time)",
+            f"  TTFT (ms)             : {ms(s['ttft'])}",
+            f"  TPOT (ms)             : {ms(s['tpot'])}",
+            f"  queueing (ms)         : {ms(s['queueing'])}",
+            f"  search (ms)           : {ms(s['search'])}",
+            f"  non-overlapped search : {ms(s['non_overlapped_search'])}",
             f"engine iterations       : {s['prefill_iterations']} prefill / "
             f"{s['decode_iterations']} decode",
             f"decode batch occupancy  : mean {s['mean_decode_batch']:.2f} "
@@ -244,6 +288,12 @@ class ServingMetrics:
             f"({s['disk_prefetch_bytes']} B overlapped with search)",
             f"doc hit rate            : {s['doc_hit_rate']:.2%}",
         ]
+        spans = s["host_seconds"]
+        if spans:
+            lines.append("host seconds by span (wall clock, a span includes "
+                         "the spans inside it)")
+            lines += [f"  {name:<22}: {sec:12.6f}"
+                      for name, sec in sorted(spans.items())]
         return "\n".join(lines)
 
 

@@ -107,40 +107,45 @@ class PagedBackend(CacheBackend):
     device's head slice once and reassembles the dense host copy, and
     promote (``load``) hands the store host numpy, which it copies straight
     to where the pool lives (``PagedKVStore._place_segment``) — never a
-    full replica on one device that the pool write would then reshard."""
+    full replica on one device that the pool write would then reshard.
+
+    Each hop runs inside a ``rt.tree.*`` span of ``metrics``, which also
+    times it."""
 
     def __init__(self, store: PagedKVStore,
-                 disk: Optional[DiskSegmentStore] = None):
+                 disk: Optional[DiskSegmentStore] = None,
+                 metrics: Optional[ServingMetrics] = None):
         self.store = store
         self.disk = disk
+        self.metrics = metrics if metrics is not None else ServingMetrics()
 
     def swap_out(self, node):
-        t0 = time.perf_counter()
-        k, v = jax.device_get(self.store.gather(node.payload_gpu))
-        node.payload_host = {"k": np.asarray(k), "v": np.asarray(v)}
-        return time.perf_counter() - t0
+        with self.metrics.span("rt.tree.demote") as sp:
+            k, v = jax.device_get(self.store.gather(node.payload_gpu))
+            node.payload_host = {"k": np.asarray(k), "v": np.asarray(v)}
+        return sp.seconds
 
     def load(self, node):
-        t0 = time.perf_counter()
-        try:
-            node.payload_gpu = self.store.put(node.payload_host["k"],
-                                              node.payload_host["v"])
-        except OutOfBlocks as e:
-            raise EvictionError(str(e))   # promote() degrades to recompute
-        jax.block_until_ready(self.store.k)
-        return time.perf_counter() - t0
+        with self.metrics.span("rt.tree.promote") as sp:
+            try:
+                node.payload_gpu = self.store.put(node.payload_host["k"],
+                                                  node.payload_host["v"])
+            except OutOfBlocks as e:
+                raise EvictionError(str(e))   # promote() degrades to recompute
+            jax.block_until_ready(self.store.k)
+        return sp.seconds
 
     def spill(self, node):
-        t0 = time.perf_counter()
-        node.payload_disk = self.disk.write(node.payload_host["k"],
-                                            node.payload_host["v"])
-        return time.perf_counter() - t0
+        with self.metrics.span("rt.tree.spill") as sp:
+            node.payload_disk = self.disk.write(node.payload_host["k"],
+                                                node.payload_host["v"])
+        return sp.seconds
 
     def fetch(self, node):
-        t0 = time.perf_counter()
-        k, v = self.disk.read(node.payload_disk)
-        node.payload_host = {"k": k, "v": v}
-        return time.perf_counter() - t0
+        with self.metrics.span("rt.tree.fetch") as sp:
+            k, v = self.disk.read(node.payload_disk)
+            node.payload_host = {"k": k, "v": v}
+        return sp.seconds
 
     def free_gpu(self, node):
         if node.payload_gpu is not None:
@@ -391,6 +396,7 @@ class ContinuousRuntime:
                                   sharding=pool_sharding)
         self._scratch_block = self.store.pool.alloc(1)[0]  # dummy-row sink
         self.disk = make_disk_store(disk_cache_dir, disk_cache_bytes)
+        self.metrics = ServingMetrics()
         self.tree = KnowledgeTree(
             gpu_cache_bytes, host_cache_bytes,
             disk_cache_bytes if self.disk is not None else 0,
@@ -398,7 +404,7 @@ class ContinuousRuntime:
             profiler=profiler or CostProfiler.from_fn(
                 lambda a, b: 1e-4 * b + 2e-8 * b * (a + b),
                 (0, 64, 256, 1024), (1, 32, 128, 512, 1024)),
-            backend=PagedBackend(self.store, self.disk),
+            backend=PagedBackend(self.store, self.disk, self.metrics),
             bytes_per_token=max(kv_bytes, 1),
         )
         self.controller = RAGController(self.tree)
@@ -414,22 +420,32 @@ class ContinuousRuntime:
                             prefill_chunk=prefill_chunk,
                             max_prefill_tokens=max_prefill_tokens),
             viable=self._job_viable, admit=self._job_admissible)
-        self.metrics = ServingMetrics()
         self.metrics.prefill_token_budget = max_prefill_tokens
         self._partial_jobs: List[_Job] = []   # jobs with live chunk state
-        self._prefill_fn = jax.jit(
-            lambda p, toks, pc, pl: M.prefill(cfg, p, {"tokens": toks},
-                                              prefix_cache=pc, prefix_len=pl),
-            static_argnames=("pl",))
+
+        # The jitted steps are named functions, so each program carries its
+        # name into the HLO and the profiler trace (``jit(rt_prefill_step)``).
+        # No step or scope name may contain a kernel's name (``paged_prefill``
+        # / ``paged_decode``): a trace reader credits a kernel with every op
+        # whose text holds that name.
+        def rt_dense_prefill(p, toks, pc, pl):
+            return M.prefill(cfg, p, {"tokens": toks}, prefix_cache=pc,
+                             prefix_len=pl)
+
+        self._prefill_fn = jax.jit(rt_dense_prefill, static_argnames=("pl",))
         # paged-prefill step: ragged chunk rows computed straight against
         # the (donated) pool planes — jit retraces per (B, Sq) bucket, like
         # the dense prefill retraces per (prefix_len, piece) shape
         _impl, _tp_mesh = attn_impl, self._mesh
-        self._paged_prefill_fn = jax.jit(
-            lambda p, toks, tb, cn, sts, qs, ql, wb, ws, kp, vp:
-            M.paged_prefill_step(cfg, p, toks, kp, vp, tb, cn, sts, qs, ql,
-                                 wb, ws, attn_impl=_impl, mesh=_tp_mesh),
-            donate_argnums=(9, 10), **self._decode_jit_kw())
+
+        def rt_prefill_step(p, toks, tb, cn, sts, qs, ql, wb, ws, kp, vp):
+            return M.paged_prefill_step(cfg, p, toks, kp, vp, tb, cn, sts, qs,
+                                        ql, wb, ws, attn_impl=_impl,
+                                        mesh=_tp_mesh)
+
+        self._paged_prefill_fn = jax.jit(rt_prefill_step,
+                                         donate_argnums=(9, 10),
+                                         **self._decode_jit_kw())
         self._decode_fn = None        # built in serve() once n_slots is known
         self.prefill_shapes = set()   # (rows, chunk bucket, table) run
         self._n_slots = 0
@@ -518,50 +534,55 @@ class ContinuousRuntime:
 
     def serve(self, requests: Sequence[Request],
               max_new_tokens: int = 4) -> List[RuntimeResult]:
-        self.max_new_tokens = max_new_tokens
-        self.admission.decode_reserve = max_new_tokens
-        max_doc = int(max(self.corpus.doc_lengths))
-        max_q = max((len(r.question_tokens) for r in requests), default=8)
-        max_ctx = self.top_k * max_doc + max_q + max_new_tokens
-        n_slots = self.store.pool.blocks_for_tokens(max_ctx) + 1
-        if n_slots > self.store.pool.n_blocks - 1:
-            raise ValueError(
-                f"paged pool too small: a worst-case request needs "
-                f"{n_slots - 1} blocks but the pool has "
-                f"{self.store.pool.n_blocks - 1} usable; raise n_blocks or "
-                f"lower top_k/doc length")
-        if n_slots != self._n_slots or self._decode_fn is None:
-            self._n_slots = n_slots
-            # paged mode reads runs, not a contiguous span: every segment of
-            # the slot mapping (<= top_k shared docs + 1 private) may end
-            # mid-block, wasting at most one table entry each.  Chunk mode
-            # splits a relocated doc into boundary seg + shared tail, so up
-            # to 2 entries per doc go to waste instead of 1.
-            per_doc = 2 if self.reuse == "chunk" else 1
-            self._n_tbl = n_slots + per_doc * self.top_k + 1
-            self._build_decode_fn()
-        first = len(self._all)
-        for r in requests:
-            self._push(max(r.arrival, self.now), "arrival", r)
-        while self._events:
-            self.now, _, kind, payload = heapq.heappop(self._events)
-            getattr(self, f"_on_{kind}")(payload)
-        unserved = [st.r.req_id for st in self._all[first:]
-                    if st.state != FINISHED]
-        if unserved:
-            raise RuntimeError(
-                f"requests {unserved} were never served (admission-starved "
-                f"to the end of the event loop — pool or tree budget too "
-                f"small for the workload)")
-        out = []
-        for st in self._all[first:]:
-            out.append(RuntimeResult(
-                req_id=st.r.req_id, tokens=list(st.tokens), ttft=st.tl.ttft,
-                docs=st.final_docs or (), alpha=st.tl.alpha, beta=st.tl.beta,
-                speculative_hit=st.tl.speculative_hit,
-                exact=st.exact, first_logits=st.first_logits))
-        out.sort(key=lambda x: x.req_id)
-        return out
+        # the runtime's entry span: its time less its children's is the
+        # event loop's own host work
+        with self.metrics.span("rt.serve"):
+            self.max_new_tokens = max_new_tokens
+            self.admission.decode_reserve = max_new_tokens
+            max_doc = int(max(self.corpus.doc_lengths))
+            max_q = max((len(r.question_tokens) for r in requests), default=8)
+            max_ctx = self.top_k * max_doc + max_q + max_new_tokens
+            n_slots = self.store.pool.blocks_for_tokens(max_ctx) + 1
+            if n_slots > self.store.pool.n_blocks - 1:
+                raise ValueError(
+                    f"paged pool too small: a worst-case request needs "
+                    f"{n_slots - 1} blocks but the pool has "
+                    f"{self.store.pool.n_blocks - 1} usable; raise n_blocks "
+                    f"or lower top_k/doc length")
+            if n_slots != self._n_slots or self._decode_fn is None:
+                self._n_slots = n_slots
+                # paged mode reads runs, not a contiguous span: every
+                # segment of the slot mapping (<= top_k shared docs + 1
+                # private) may end mid-block, wasting at most one table
+                # entry each.  Chunk mode splits a relocated doc into
+                # boundary seg + shared tail, so up to 2 entries per doc go
+                # to waste instead of 1.
+                per_doc = 2 if self.reuse == "chunk" else 1
+                self._n_tbl = n_slots + per_doc * self.top_k + 1
+                self._build_decode_fn()
+            first = len(self._all)
+            for r in requests:
+                self._push(max(r.arrival, self.now), "arrival", r)
+            while self._events:
+                self.now, _, kind, payload = heapq.heappop(self._events)
+                getattr(self, f"_on_{kind}")(payload)
+            unserved = [st.r.req_id for st in self._all[first:]
+                        if st.state != FINISHED]
+            if unserved:
+                raise RuntimeError(
+                    f"requests {unserved} were never served (admission-"
+                    f"starved to the end of the event loop — pool or tree "
+                    f"budget too small for the workload)")
+            out = []
+            for st in self._all[first:]:
+                out.append(RuntimeResult(
+                    req_id=st.r.req_id, tokens=list(st.tokens),
+                    ttft=st.tl.ttft, docs=st.final_docs or (),
+                    alpha=st.tl.alpha, beta=st.tl.beta,
+                    speculative_hit=st.tl.speculative_hit,
+                    exact=st.exact, first_logits=st.first_logits))
+            out.sort(key=lambda x: x.req_id)
+            return out
 
     # ------------------------------------------------------------------
     # arrivals & staged retrieval (host-CPU lanes, one per request)
@@ -587,14 +608,14 @@ class ContinuousRuntime:
             # machinery degenerates (no stage events, no speculative
             # prefills, search_time identically 0), and the single final
             # job enters the scheduler at arrival.
-            docs = tuple(int(d) for d in self.index.search(r.query_vec, k))
+            with self.metrics.span("rt.retrieval", req_id=r.req_id):
+                docs = tuple(int(d) for d in self.index.search(r.query_vec, k))
             st.tl.search_end = self.now
             st.final_docs = docs
             job = _Job(req=st, docs=docs, speculative=False,
                        enqueued=self.now)
             st.jobs.append(job)
-            cached, compute = self._job_lens(job)
-            self.sched.submit(job, cached, compute)
+            self._submit(job)
             self._prefetch_disk(docs)
             st.tl.queue_enter = self.now
             self._engine_kick()
@@ -602,16 +623,17 @@ class ContinuousRuntime:
         # materialize stages, measuring the real scan cost of each stage;
         # the per-request search lane advances by max(measured, analytic)
         t = self.now
-        it = iter(self.index.staged_search(r.query_vec, k))
-        while True:
-            t0 = time.perf_counter()
-            try:
-                stage = next(it)
-            except StopIteration:
-                break
-            wall = time.perf_counter() - t0
-            t += max(wall, stage.seconds) * self.search_time_scale
-            self._push(t, "stage", (st, stage))
+        with self.metrics.span("rt.retrieval", req_id=r.req_id):
+            it = iter(self.index.staged_search(r.query_vec, k))
+            while True:
+                t0 = time.perf_counter()
+                try:
+                    stage = next(it)
+                except StopIteration:
+                    break
+                wall = time.perf_counter() - t0
+                t += max(wall, stage.seconds) * self.search_time_scale
+                self._push(t, "stage", (st, stage))
 
     def _on_stage(self, payload) -> None:
         st, stage = payload
@@ -630,8 +652,7 @@ class ContinuousRuntime:
             job = _Job(req=st, docs=d, speculative=not stage.is_final,
                        enqueued=self.now)
             st.jobs.append(job)
-            cached, compute = self._job_lens(job)
-            self.sched.submit(job, cached, compute)
+            self._submit(job)
             self._prefetch_disk(d)
             if not stage.is_final:
                 self.metrics.spec_prefills += 1
@@ -665,6 +686,13 @@ class ContinuousRuntime:
                     self.metrics.disk_prefetches += 1
                     self.metrics.disk_prefetch_bytes += moved
 
+    def _submit(self, job: _Job) -> None:
+        """Queue ``job`` with the scheduler at its current (cached, compute)
+        token counts."""
+        with self.metrics.span("rt.schedule", req_id=job.req.r.req_id):
+            cached, compute = self._job_lens(job)
+            self.sched.submit(job, cached, compute)
+
     def _maybe_finalize(self, st: _ReqRun) -> None:
         """Search done: if a prefill for the final docs already completed,
         the speculation paid off — emit the first token now."""
@@ -680,18 +708,19 @@ class ContinuousRuntime:
 
     def _engine_kick(self) -> None:
         while not self.engine_busy:
-            self._sweep_stale_partials()
-            self.admission.invalidate()   # fresh resource snapshot per kick
-            if self._force_decode and self.running:
-                # a pagination just failed on shared-block pressure: run one
-                # decode iteration first so running requests make progress
-                # toward releasing their tables (livelock guard)
+            with self.metrics.span("rt.schedule"):
+                self._sweep_stale_partials()
+                self.admission.invalidate()   # fresh snapshot per kick
+                # after a pagination failed on shared-block pressure, run
+                # one decode iteration first so running requests make
+                # progress toward releasing their tables (livelock guard)
+                force = self._force_decode and bool(self.running)
                 self._force_decode = False
+                act = None if force else self.sched.next_action(
+                    len(self.running), refresh=self._job_lens)
+            if force:
                 self._start_decode()
                 return
-            self._force_decode = False
-            act = self.sched.next_action(len(self.running),
-                                         refresh=self._job_lens)
             if act.kind == PREFILL:
                 self._start_prefill_batch(act.chunks)
                 return
@@ -732,8 +761,7 @@ class ContinuousRuntime:
         job = _Job(req=victim, docs=victim.final_docs, speculative=False,
                    enqueued=self.now)
         victim.jobs.append(job)
-        cached, compute = self._job_lens(job)
-        self.sched.submit(job, cached, compute)
+        self._submit(job)
 
     # ---- chunked + batched prefill -------------------------------------
 
@@ -758,12 +786,17 @@ class ContinuousRuntime:
                 else:
                     self.sched.abort_prefill(job)
                 continue
+            rid = job.req.r.req_id
             if job.cs is None:
-                self._begin_chunked(job)
+                with self.metrics.span("rt.plan", req_id=rid):
+                    self._begin_chunked(job)
             if self.attn == "paged":
-                row = self._prep_paged_chunk(job)
+                with self.metrics.span("rt.prefill.pack", req_id=rid):
+                    row = self._prep_paged_chunk(job)
                 if row is None:
-                    continue           # OutOfBlocks: job aborted + requeued
+                    # OutOfBlocks: abort and requeue in place
+                    self._abort_chunked(job, requeue=True)
+                    continue
                 rows.append(row)
                 executed += row[-1]
             else:
@@ -995,7 +1028,7 @@ class ContinuousRuntime:
         and the run table covering cached prefix + everything computed so
         far INCLUDING this chunk (causal masking over absolute positions
         keeps row i from seeing slots past it).  Returns None if the pool
-        cannot hold the piece (job aborted + requeued in place)."""
+        cannot hold the piece; the caller then aborts and requeues the job."""
         cs = job.cs
         n = cs.pieces.pop(0)
         while cs.seg_idx < len(cs.segs) and \
@@ -1017,12 +1050,10 @@ class ContinuousRuntime:
             need = (self.store.pool.blocks_for_tokens(pg.n_tokens + take)
                     - len(pg.blocks))
             if need > 0 and not self._reclaim_blocks(need):
-                self._abort_chunked(job, requeue=True)
                 return None
             try:
                 blk, slot = self.store.extend_alloc(pg, take)
             except OutOfBlocks:
-                self._abort_chunked(job, requeue=True)
                 return None
             toks[off:off + take] = seg[cs.seg_off:cs.seg_off + take]
             wblk[off:off + take] = blk
@@ -1076,35 +1107,38 @@ class ContinuousRuntime:
         block and are fully masked (q_len), so every real row's output —
         and therefore every token — is independent of what shares the
         batch."""
-        B = max(self.sched.config.max_prefill_bs, len(rows))
-        Sq = max(8, 1 << (max(r[-1] for r in rows) - 1).bit_length())
-        T = self._n_tbl
-        toks = np.zeros((B, Sq), np.int32)
-        wblk = np.full((B, Sq), self._scratch_block, np.int32)
-        wslot = np.zeros((B, Sq), np.int32)
-        tables = np.full((B, T), self._scratch_block, np.int32)
-        counts = np.zeros((B, T), np.int32)
-        starts = np.zeros((B, T), np.int32)
-        q_start = np.zeros((B,), np.int32)
-        q_len = np.zeros((B,), np.int32)
-        self.prefill_shapes.add((B, Sq, T))
-        for i, (job, t, wb, ws, qs, tb, cn, st_, n) in enumerate(rows):
-            toks[i, :n] = t
-            wblk[i, :n] = wb
-            wslot[i, :n] = ws
-            tables[i] = tb
-            counts[i] = cn
-            starts[i] = st_
-            q_start[i] = qs
-            q_len[i] = n
-        with self._trace_ctx():
+        it = len(self.metrics.iterations)
+        with self.metrics.span("rt.prefill.pack", it=it):
+            B = max(self.sched.config.max_prefill_bs, len(rows))
+            Sq = max(8, 1 << (max(r[-1] for r in rows) - 1).bit_length())
+            T = self._n_tbl
+            toks = np.zeros((B, Sq), np.int32)
+            wblk = np.full((B, Sq), self._scratch_block, np.int32)
+            wslot = np.zeros((B, Sq), np.int32)
+            tables = np.full((B, T), self._scratch_block, np.int32)
+            counts = np.zeros((B, T), np.int32)
+            starts = np.zeros((B, T), np.int32)
+            q_start = np.zeros((B,), np.int32)
+            q_len = np.zeros((B,), np.int32)
+            self.prefill_shapes.add((B, Sq, T))
+            for i, (job, t, wb, ws, qs, tb, cn, st_, n) in enumerate(rows):
+                toks[i, :n] = t
+                wblk[i, :n] = wb
+                wslot[i, :n] = ws
+                tables[i] = tb
+                counts[i] = cn
+                starts[i] = st_
+                q_start[i] = qs
+                q_len[i] = n
+        with self.metrics.span("rt.prefill.launch", it=it), self._trace_ctx():
             logits, self.store.k, self.store.v = self._paged_prefill_fn(
                 self.params, jnp.asarray(toks), jnp.asarray(tables),
                 jnp.asarray(counts), jnp.asarray(starts),
                 jnp.asarray(q_start), jnp.asarray(q_len),
                 jnp.asarray(wblk), jnp.asarray(wslot),
                 self.store.k, self.store.v)
-        logits = jax.block_until_ready(logits)
+        with self.metrics.span("rt.prefill.wait", it=it):
+            logits = jax.block_until_ready(logits)
         for i, row in enumerate(rows):
             row[0].cs.logits = logits[i:i + 1]       # (1, 1, V)
 
@@ -1135,9 +1169,13 @@ class ContinuousRuntime:
                     n.pinned = False
                 self.metrics.wasted_prefills += 1
                 continue
+            rid = st.r.req_id
+            with self.metrics.span("rt.first_token", req_id=rid):
+                first_token = int(jnp.argmax(cs.logits[0, -1]))
+                first_logits = np.asarray(cs.logits[0, -1])
             res = _PrefillResult(
                 docs=job.docs, cache=cs.cache,
-                first_token=int(jnp.argmax(cs.logits[0, -1])),
+                first_token=first_token,
                 total_len=cs.plen,
                 alpha=cs.plan.alpha, beta=cs.plan.beta,
                 hit_docs=cs.plan.hit_docs,
@@ -1145,16 +1183,17 @@ class ContinuousRuntime:
                 speculative=job.speculative, started=job.started,
                 hit_runs=hit_runs, pg_segs=pg_segs,
                 layout=list(cs.layout), exact=cs.plan.exact,
-                first_logits=np.asarray(cs.logits[0, -1]))
-            if cs.plan.chunks is not None:
-                self._commit_paged_chunks(
-                    cs.plan, [pg_segs[i] for i in cs.miss_segs])
-            elif self.attn == "paged":
-                self._commit_paged(cs.plan, pg_segs[:len(cs.doc_bounds)])
-            else:
-                payloads = [(start, length, cs.cache)
-                            for start, length in cs.doc_bounds]
-                self._commit_payloads(cs.plan, payloads)
+                first_logits=first_logits)
+            with self.metrics.span("rt.commit", req_id=rid):
+                if cs.plan.chunks is not None:
+                    self._commit_paged_chunks(
+                        cs.plan, [pg_segs[i] for i in cs.miss_segs])
+                elif self.attn == "paged":
+                    self._commit_paged(cs.plan, pg_segs[:len(cs.doc_bounds)])
+                else:
+                    payloads = [(start, length, cs.cache)
+                                for start, length in cs.doc_bounds]
+                    self._commit_payloads(cs.plan, payloads)
             st.results[job.docs] = res
             if st.final_docs is not None and job.docs == st.final_docs:
                 self._first_token(st, res, max(self.now, st.tl.search_end))
@@ -1205,8 +1244,7 @@ class ContinuousRuntime:
             redo = _Job(req=job.req, docs=job.docs,
                         speculative=job.speculative, enqueued=self.now)
             job.req.jobs.append(redo)
-            cached, compute = self._job_lens(redo)
-            self.sched.submit(redo, cached, compute)
+            self._submit(redo)
 
     def _commit_payloads(self, plan, payloads) -> None:
         """Page the new per-doc KV segments into the store and insert them
@@ -1327,8 +1365,7 @@ class ContinuousRuntime:
         job = _Job(req=st, docs=st.final_docs, speculative=False,
                    enqueued=self.now)
         st.jobs.append(job)
-        cached, compute = self._job_lens(job)
-        self.sched.submit(job, cached, compute)
+        self._submit(job)
 
     def _paginate(self, st: _ReqRun, res: _PrefillResult) -> bool:
         """Build the request's decode slot mapping: refcount-share EVERY
@@ -1470,14 +1507,14 @@ class ContinuousRuntime:
         impl = self.attn_impl
         tp_mesh = self._mesh
 
-        def step(params, toks, tables, counts, starts, pos,
-                 write_blk, write_slot, k_pages, v_pages):
+        def rt_decode_step(params, toks, tables, counts, starts, pos,
+                           write_blk, write_slot, k_pages, v_pages):
             logits, k_pages, v_pages = M.paged_decode_step(
                 cfg, params, toks, k_pages, v_pages, tables, counts, starts,
                 write_blk, write_slot, pos, attn_impl=impl, mesh=tp_mesh)
             return jnp.argmax(logits[:, -1], axis=-1), k_pages, v_pages
 
-        self._decode_fn = jax.jit(step, donate_argnums=(8, 9),
+        self._decode_fn = jax.jit(rt_decode_step, donate_argnums=(8, 9),
                                   **self._decode_jit_kw())
         # warm up the single decode shape (dummy rows decode token 0 into
         # the scratch block, exactly like a padding row in _start_decode)
@@ -1546,7 +1583,8 @@ class ContinuousRuntime:
         B = self.sched.config.max_batch
         S = self._n_slots * self.store.block_size   # max token positions
 
-        def step(params, toks, blk_map, slot_map, lengths, k_pages, v_pages):
+        def rt_dense_decode_step(params, toks, blk_map, slot_map, lengths,
+                                 k_pages, v_pages):
             # token-level slot mapping (vLLM-style slot_mapping): position p
             # of request b lives at (blk_map[b, p], slot_map[b, p]), so the
             # gathered dense sequence is hole-free even when shared tree
@@ -1564,7 +1602,7 @@ class ContinuousRuntime:
             v_pages = scatter_slots(v_pages, blk, slot, newv)
             return jnp.argmax(logits[:, -1], axis=-1), k_pages, v_pages
 
-        self._decode_fn = jax.jit(step, donate_argnums=(5, 6),
+        self._decode_fn = jax.jit(rt_dense_decode_step, donate_argnums=(5, 6),
                                   **self._decode_jit_kw())
         # warm up the single decode shape so its compile never lands on the
         # serving clock (all dummy rows write into the scratch block)
@@ -1583,17 +1621,15 @@ class ContinuousRuntime:
         self.engine_busy = True
         self.metrics.record_iteration("decode", len(batch))
         t0 = time.perf_counter()
-        if self.attn == "paged":
-            args = self._paged_decode_args(batch)
-            with self._trace_ctx():
-                next_toks, self.store.k, self.store.v = self._decode_fn(
-                    self.params, *args, self.store.k, self.store.v)
-        else:
-            with self._trace_ctx():
-                next_toks, self.store.k, self.store.v = self._decode_fn(
-                    self.params, *self._dense_decode_args(batch),
-                    self.store.k, self.store.v)
-        next_toks = np.asarray(jax.block_until_ready(next_toks))
+        it = len(self.metrics.iterations)
+        with self.metrics.span("rt.decode.pack", it=it):
+            args = (self._paged_decode_args(batch) if self.attn == "paged"
+                    else self._dense_decode_args(batch))
+        with self.metrics.span("rt.decode.launch", it=it), self._trace_ctx():
+            next_toks, self.store.k, self.store.v = self._decode_fn(
+                self.params, *args, self.store.k, self.store.v)
+        with self.metrics.span("rt.decode.wait", it=it):
+            next_toks = np.asarray(jax.block_until_ready(next_toks))
         dt = time.perf_counter() - t0
         self._push(self.now + dt, "decode_done",
                    (batch, [int(t) for t in next_toks[:len(batch)]]))

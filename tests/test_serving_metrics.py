@@ -76,6 +76,51 @@ def test_summary_aggregates():
     assert "TTFT" in m.format_report()
 
 
+def test_span_adds_wall_seconds_inclusive_of_inner_spans(monkeypatch):
+    from repro.serving import metrics as metrics_mod
+    ticks = iter([1.0, 1.5, 2.25, 4.0, 10.0, 10.5])
+    monkeypatch.setattr(metrics_mod.time, "perf_counter", lambda: next(ticks))
+    m = ServingMetrics()
+    with m.span("rt.commit", req_id=7):          # 1.0 .. 4.0
+        with m.span("rt.tree.demote"):           # 1.5 .. 2.25
+            pass
+    with m.span("rt.commit", req_id=8) as sp:    # 10.0 .. 10.5
+        pass
+    assert sp.seconds == pytest.approx(0.5)
+    assert m.host_seconds["rt.commit"] == pytest.approx(3.5)
+    assert m.host_seconds["rt.tree.demote"] == pytest.approx(0.75)
+    assert m.summary()["host_seconds"] == {"rt.commit": pytest.approx(3.5),
+                                           "rt.tree.demote": pytest.approx(0.75)}
+
+
+def test_span_records_even_when_the_block_raises():
+    m = ServingMetrics()
+    with pytest.raises(KeyError):
+        with m.span("rt.schedule"):
+            raise KeyError("x")
+    assert m.host_seconds["rt.schedule"] >= 0.0 and "rt.schedule" in m.host_seconds
+
+
+def test_report_labels_runtime_clock_and_lists_host_spans():
+    m = ServingMetrics()
+    tl = m.timeline(0, 0.0)
+    tl.first_token = 0.25
+    rep = m.format_report()
+    head, _, rest = rep.partition("runtime clock")
+    assert "not wall time" in rest.splitlines()[0]
+    for label in ("TTFT (ms)", "TPOT (ms)", "queueing (ms)", "search (ms)",
+                  "non-overlapped search"):
+        assert label not in head and label in rest
+    assert "host seconds by span" not in rep     # nothing timed yet
+    m.host_seconds["rt.retrieval"] += 0.002
+    m.host_seconds["rt.commit"] += 0.031
+    rep = m.format_report()
+    block = rep.split("host seconds by span", 1)[1].splitlines()[1:]
+    assert [ln.split(":")[0].strip() for ln in block] == ["rt.commit",
+                                                          "rt.retrieval"]
+    assert "0.031000" in block[0] and "0.002000" in block[1]
+
+
 # ---------------------------------------------------------------------------
 # degenerate inputs: zero completed requests, all-idle replicas (PR 6)
 # ---------------------------------------------------------------------------
