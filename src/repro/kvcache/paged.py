@@ -47,6 +47,81 @@ def scatter_slots(pages, blk, slot, vals):
     return pages.at[:, blk, :, slot].set(vals)
 
 
+def max_write_runs(rows: int, block: int, segments: int = 1) -> int:
+    """The most pages one chunk row of ``rows`` tokens can write, when its
+    tokens cross at most ``segments`` segments.
+
+    A segment's pages are its own and filled in order, so a row writes one
+    run per page it touches.  The row's first segment may resume mid-page,
+    at a slot ``s0 <= block - 1``; every later segment starts a fresh page.
+    Tokens ``t_0 + ... + t_{g-1} <= rows`` then touch at most
+    ``ceil((s0 + t_0) / block) + sum_{i>0} ceil(t_i / block)
+    <= (rows + (g + 1) * (block - 1)) // block`` pages, and never more
+    pages than tokens.  A chunked splitter (``prefill_piece_sizes`` with
+    chunk > 0, or one piece per segment) gives ``g = 1``."""
+    return min(rows, (rows + (segments + 1) * (block - 1)) // block)
+
+
+def run_starts(blk, slot, valid):
+    """Where each write run of a chunk row begins: (..., S) bool over the
+    row's tokens.  A run is the valid tokens that go to one page; one starts
+    at the row's first token, at slot 0, and wherever the page changes.
+    Works on numpy and jax arrays alike."""
+    xp = np if isinstance(blk, np.ndarray) else jnp
+    first = xp.arange(blk.shape[-1]) == 0
+    prev = xp.concatenate([blk[..., :1], blk[..., :-1]], axis=-1)
+    return valid & (first | (slot == 0) | (blk != prev))
+
+
+def write_pages(pages, li, write_blk, write_slot, q_len, vals, n_runs: int):
+    """Write chunk KV into layer ``li`` of the pool a whole page at a time.
+
+    pages: (L, n_blocks, KV, block, hd) pool plane.  write_blk/write_slot:
+    (B, S) page coordinates of each row's tokens, of which the first
+    ``q_len[b]`` are valid.  vals: (B, S, KV, hd).  n_runs: the static
+    number of runs written per row, at least each row's count of
+    ``run_starts`` (``max_write_runs`` bounds it).
+
+    Each run reads its page, puts the run's tokens at their slots, keeps
+    every other slot, and writes the page back with one
+    ``dynamic_update_slice`` that covers the whole (KV, block, hd) window
+    and indexes only the two leading dims.  That is a write in the pool's
+    own layout: a token-level scatter (``scatter_slots``) makes the TPU
+    compiler copy the whole pool into another tiling and back, once per
+    layer.  The runs are written one after another, each reading the pool
+    as the runs before it left it, so a run with no tokens — a row's spare
+    runs, all of a padding row's — writes its page back unchanged; it aims
+    at the page of the row's last token, the store's scratch block
+    whenever the row is padded.  The result equals the token scatter's
+    bit for bit on every page the valid tokens write."""
+    B, S = write_blk.shape
+    KV, block, hd = pages.shape[2:]
+    valid = jnp.arange(S)[None] < q_len[:, None]
+    start = run_starts(write_blk, write_slot, valid)
+    run = jnp.cumsum(start, axis=1) - 1                        # (B, S)
+    member = (run[..., None] == jnp.arange(n_runs)) & valid[..., None]
+    length = member.sum(axis=1)                                # (B, P)
+    first = jnp.where(length > 0, jnp.argmax(member, axis=1), S - 1)
+    blk = jnp.take_along_axis(write_blk, first, axis=1)
+    slot0 = jnp.take_along_axis(write_slot, first, axis=1)
+    # rows in page layout, padded a page each side so that the page-long
+    # window of token ``first - slot0`` is in range for any slot0 < block
+    rows = jnp.pad(vals.astype(pages.dtype).transpose(0, 2, 1, 3),
+                   ((0, 0), (0, 0), (block, block), (0, 0)))
+    slots = jnp.arange(block)
+    for b in range(B):
+        for r in range(n_runs):
+            at = (li, blk[b, r], 0, 0, 0)
+            new = jax.lax.dynamic_slice(
+                rows[b], (0, first[b, r] - slot0[b, r] + block, 0),
+                (KV, block, hd))
+            old = jax.lax.dynamic_slice(pages, at, (1, 1, KV, block, hd))[0, 0]
+            keep = (slots < slot0[b, r]) | (slots >= slot0[b, r] + length[b, r])
+            page = jnp.where(keep[None, :, None], old, new)
+            pages = jax.lax.dynamic_update_slice(pages, page[None, None], at)
+    return pages
+
+
 class OutOfBlocks(RuntimeError):
     pass
 
@@ -212,7 +287,7 @@ class PagedKVStore:
     def extend_alloc(self, seg: "PagedSegment",
                      n: int) -> Tuple[np.ndarray, np.ndarray]:
         """Reserve capacity for ``n`` more tokens WITHOUT writing data —
-        the paged prefill kernel scatters KV into the pool in place, so the
+        the paged prefill step writes KV into the pool in place, so the
         store only needs to hand out the (block, slot) coordinates.
 
         Mutates ``seg`` (blocks list + n_tokens) and returns int32

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.kvcache.paged import max_write_runs, write_pages
 from repro.models.config import ModelConfig
 from repro.models import layers as L
 
@@ -548,9 +549,9 @@ def paged_decode_step(cfg: ModelConfig, params, tokens, k_pages, v_pages,
     (token-level slot mapping compressed to runs — see
     kernels/paged_attention.py for the contract).  write_blk/write_slot:
     (B,) page coordinates of the token being decoded — its KV is appended
-    in place per layer BEFORE attention, and ``counts`` must already
-    include it.  pos: (B,) sequence length *including* that token (same
-    semantics as ``decode_step``).
+    in place per layer BEFORE attention, one page write a row, and
+    ``counts`` must already include it.  pos: (B,) sequence length
+    *including* that token (same semantics as ``decode_step``).
 
     Returns (logits, k_pages, v_pages).  Attention families only —
     recurrent state cannot be paged per-block.
@@ -568,6 +569,9 @@ def paged_decode_step(cfg: ModelConfig, params, tokens, k_pages, v_pages,
     B = x.shape[0]
     windows = _layer_windows_arr(cfg)
     rope_pos = (pos - 1)[:, None]
+    # each row writes its one token as a one-token chunk row
+    wblk, wslot = write_blk[:, None], write_slot[:, None]
+    one = jnp.ones((B,), jnp.int32)
 
     def body(carry, xs):
         x, kp, vp = carry
@@ -576,12 +580,9 @@ def paged_decode_step(cfg: ModelConfig, params, tokens, k_pages, v_pages,
         q, k, v = _qkv(cfg, p, h)                          # S == 1
         q = L.apply_rope(q, rope_pos, cfg.rope_theta)
         k = L.apply_rope(k, rope_pos, cfg.rope_theta)
-        # [li, blk, :, slot] -> (B, KV, hd): the index arrays' dims lead
         with jax.named_scope("kv_write"):
-            kp = kp.at[li, write_blk, :, write_slot].set(
-                k[:, 0].astype(kp.dtype))
-            vp = vp.at[li, write_blk, :, write_slot].set(
-                v[:, 0].astype(vp.dtype))
+            kp = write_pages(kp, li, wblk, wslot, one, k, 1)
+            vp = write_pages(vp, li, wblk, wslot, one, v, 1)
         with jax.named_scope("attn"):
             o = ops.paged_decode_attention(
                 q[:, 0], kp, vp, tables, counts, starts, pos - 1, li, w,
@@ -603,7 +604,7 @@ def paged_decode_step(cfg: ModelConfig, params, tokens, k_pages, v_pages,
 def paged_prefill_step(cfg: ModelConfig, params, tokens, k_pages, v_pages,
                        tables, counts, starts, q_start, q_len, write_blk,
                        write_slot, *, attn_impl: str | None = None,
-                       mesh=None):
+                       mesh=None, max_segments: int = 1):
     """One ragged prefill chunk computed straight against the paged pool —
     the prefill twin of ``paged_decode_step`` (no dense (L, B, S, KV, hd)
     gather, no per-chunk dense KV to re-page afterwards).
@@ -616,8 +617,13 @@ def paged_prefill_step(cfg: ModelConfig, params, tokens, k_pages, v_pages,
     allocated pages (counts include the chunk's own tokens — causal masking
     over absolute positions keeps later rows from seeing earlier garbage).
     write_blk/write_slot: (B, Sq) page coordinates for every chunk token —
-    KV is scattered in place per layer BEFORE attention; padding rows point
-    at the store's scratch block, which no live run ever reads.
+    KV is written in place per layer BEFORE attention, a page at a time
+    (``kvcache.paged.write_pages``); padding rows point at the store's
+    scratch block, which no live run ever reads.  max_segments: the most
+    segments one row's tokens cross, which bounds the pages a row writes
+    (``max_write_runs``); 1 under a chunked splitter.  A row that writes
+    more pages than the bound allows loses the excess, so a caller checks
+    its rows against it (the runtime does, in ``_run_paged_rows``).
 
     Returns (logits, k_pages, v_pages) with logits (B, 1, V) taken at each
     row's LAST VALID token, so the final chunk's call yields the first-token
@@ -637,6 +643,7 @@ def paged_prefill_step(cfg: ModelConfig, params, tokens, k_pages, v_pages,
     B, Sq = tokens.shape
     windows = _layer_windows_arr(cfg)
     positions = q_start[:, None] + jnp.arange(Sq, dtype=jnp.int32)[None]
+    n_runs = max_write_runs(Sq, k_pages.shape[3], max_segments)
 
     def body(carry, xs):
         x, kp, vp = carry
@@ -645,10 +652,9 @@ def paged_prefill_step(cfg: ModelConfig, params, tokens, k_pages, v_pages,
         q, k, v = _qkv(cfg, p, h)                          # (B, Sq, ., hd)
         q = L.apply_rope(q, positions, cfg.rope_theta)
         k = L.apply_rope(k, positions, cfg.rope_theta)
-        # [li, blk, :, slot] -> (B, Sq, KV, hd): the index arrays' dims lead
         with jax.named_scope("kv_write"):
-            kp = kp.at[li, write_blk, :, write_slot].set(k.astype(kp.dtype))
-            vp = vp.at[li, write_blk, :, write_slot].set(v.astype(vp.dtype))
+            kp = write_pages(kp, li, write_blk, write_slot, q_len, k, n_runs)
+            vp = write_pages(vp, li, write_blk, write_slot, q_len, v, n_runs)
         with jax.named_scope("attn"):
             o = ops.paged_prefill_attention(
                 q.transpose(0, 2, 1, 3), kp, vp, tables, counts, starts,

@@ -79,7 +79,7 @@ from repro.core.profiler import CostProfiler
 from repro.core.speculative import SpecState, SpeculativeController
 from repro.kvcache.paged import (DiskSegmentStore, OutOfBlocks, PagedKVStore,
                                  PagedSegment, gather_slots, make_disk_store,
-                                 scatter_slots)
+                                 max_write_runs, run_starts, scatter_slots)
 from repro.launch.mesh import make_serving_mesh
 from repro.launch.sharding import (assert_tp_compatible, pool_kv_spec,
                                    serving_param_shardings)
@@ -210,8 +210,8 @@ class _ChunkState:
     logits: Optional[object] = None
     # paged-prefill mode: no dense KV at all.  hit_runs snapshot the shared
     # (pinned + incref'd) prefix nodes' pages; pg_segs hold one (initially
-    # empty) segment per to-compute segment in ``segs`` — the kernel
-    # scatters each chunk's KV straight into their freshly allocated pages.
+    # empty) segment per to-compute segment in ``segs`` — the step
+    # writes each chunk's KV straight into their freshly allocated pages.
     hit_runs: List[Tuple[List[int], int]] = dataclasses.field(
         default_factory=list)
     pg_segs: List[PagedSegment] = dataclasses.field(default_factory=list)
@@ -437,11 +437,17 @@ class ContinuousRuntime:
         # the (donated) pool planes — jit retraces per (B, Sq) bucket, like
         # the dense prefill retraces per (prefix_len, piece) shape
         _impl, _tp_mesh = attn_impl, self._mesh
+        # the most segments one prefill row crosses, which bounds the pages
+        # it writes: a chunked splitter keeps each piece inside a segment;
+        # an unchunked prefix plan walks every uncached doc, then the question
+        self._row_segments = (1 if prefill_chunk > 0 or reuse == "chunk"
+                              else top_k + 1)
+        _segments = self._row_segments
 
         def rt_prefill_step(p, toks, tb, cn, sts, qs, ql, wb, ws, kp, vp):
             return M.paged_prefill_step(cfg, p, toks, kp, vp, tb, cn, sts, qs,
                                         ql, wb, ws, attn_impl=_impl,
-                                        mesh=_tp_mesh)
+                                        mesh=_tp_mesh, max_segments=_segments)
 
         self._paged_prefill_fn = jax.jit(rt_prefill_step,
                                          donate_argnums=(9, 10),
@@ -1121,7 +1127,14 @@ class ContinuousRuntime:
             q_start = np.zeros((B,), np.int32)
             q_len = np.zeros((B,), np.int32)
             self.prefill_shapes.add((B, Sq, T))
+            n_runs = max_write_runs(Sq, self.store.block_size,
+                                    self._row_segments)
             for i, (job, t, wb, ws, qs, tb, cn, st_, n) in enumerate(rows):
+                # the step writes n_runs pages a row and would drop the rest
+                runs = int(run_starts(wb, ws, True).sum())
+                assert runs <= n_runs, (
+                    f"prefill row of {n} tokens writes {runs} pages; the "
+                    f"{Sq}-row step writes at most {n_runs}")
                 toks[i, :n] = t
                 wblk[i, :n] = wb
                 wslot[i, :n] = ws
@@ -1272,7 +1285,7 @@ class ContinuousRuntime:
 
     def _commit_paged(self, plan, doc_segs) -> None:
         """Paged twin of ``_commit_payloads``: the per-doc KV already lives
-        in pool blocks (the prefill kernel scattered it in place), so
+        in pool blocks (the prefill step wrote it in place), so
         committing is pure refcounting — share each segment to mint the
         tree's independent reference, then drop it again for every segment
         the tree declined (duplicate doc path or insert stopped early)."""

@@ -314,6 +314,32 @@ def test_paged_decode_step_matches_decode_step(serving_setup):
                              np.float32)).max() > 0
 
 
+@pytest.mark.parametrize("padding_rows", [0, 2])
+def test_decode_page_write_matches_token_scatter(padding_rows):
+    """The decode step's KV write — one token a row, one page write a row,
+    as ``paged_decode_step`` calls it — leaves every page but the scratch
+    block bit-identical to the token scatter it replaced, with padding rows
+    all aimed at slot 0 of the scratch block."""
+    from repro.kvcache.paged import write_pages
+    L_, n_blocks, KV, bs, hd, li = 2, 12, 2, 8, 4, 1
+    rng = np.random.default_rng(padding_rows)
+    live = 4
+    wblk = np.zeros(live + padding_rows, np.int32)     # block 0 = scratch
+    wslot = np.zeros(live + padding_rows, np.int32)
+    wblk[:live] = rng.permutation(n_blocks - 1)[:live] + 1
+    wslot[:live] = [0, 3, bs - 1, 5]
+    B = len(wblk)
+    pages = jnp.asarray(rng.standard_normal((L_, n_blocks, KV, bs, hd)),
+                        jnp.bfloat16)
+    k = jnp.asarray(rng.standard_normal((B, 1, KV, hd)))
+    got = jax.jit(write_pages, static_argnums=6)(
+        pages, jnp.int32(li), jnp.asarray(wblk)[:, None],
+        jnp.asarray(wslot)[:, None], jnp.ones((B,), jnp.int32), k, 1)
+    want = pages.at[li, wblk, :, wslot].set(k[:, 0].astype(pages.dtype))
+    keep = np.arange(1, n_blocks)
+    assert np.array_equal(np.asarray(got)[:, keep], np.asarray(want)[:, keep])
+
+
 def test_runtime_paged_tokens_match_dense_and_tables_pack_runs(serving_setup):
     """e2e: --attn paged reproduces the dense engine's greedy tokens, and
     the packed run tables obey the slot-mapping contract (runs start at

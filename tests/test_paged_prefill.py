@@ -404,6 +404,91 @@ def _alloc_plan(cfg, n_tokens, bs, n_blocks, rng):
     return wblk, wslot, tables, counts, starts
 
 
+# Chunk rows for the page-write parity test: (block, bucket, segments, rows).
+# A row is (s0, [t0, t1, ...]): t0 tokens resume a segment at slot s0, each
+# later segment starts a fresh page; None is a padding row.
+WRITE_CASES = {
+    "mid_page": (8, 8, 1, [(5, [3])]),
+    # 32 tokens from slot 7: five pages, the bound, and no padding token
+    "crosses_pages": (8, 32, 1, [(7, [32])]),
+    # three segments at the bound's largest run count: 2 + 3 + 2 pages
+    "segments": (8, 32, 3, [(7, [2, 17, 13])]),
+    "padding": (8, 16, 2, [(0, [10]), None, (3, [4, 5])]),
+    "short_bucket": (16, 8, 1, [(13, [8]), (0, [5])]),
+}
+
+
+def _write_rows(block, Sq, rows, n_blocks, rng, scratch=0):
+    """(B, Sq) write coords for chunk rows on fresh random pages; padding
+    tokens and padding rows aim at the scratch block."""
+    free = list(rng.permutation([b for b in range(n_blocks) if b != scratch]))
+    wblk = np.full((len(rows), Sq), scratch, np.int32)
+    wslot = np.zeros((len(rows), Sq), np.int32)
+    q_len = np.zeros(len(rows), np.int32)
+    for b, row in enumerate(rows):
+        if row is None:
+            continue
+        s0, lens = row
+        i = 0
+        for g, t in enumerate(lens):
+            pos = np.arange(t) + (s0 if g == 0 else 0)
+            pages = [free.pop() for _ in range(-(-(pos[-1] + 1) // block))]
+            wblk[b, i:i + t] = np.asarray(pages)[pos // block]
+            wslot[b, i:i + t] = pos % block
+            i += t
+        q_len[b] = i
+    return wblk, wslot, q_len
+
+
+@pytest.mark.parametrize("case", list(WRITE_CASES))
+def test_write_pages_matches_token_scatter(case):
+    """The page-granular KV write leaves every page but the scratch block
+    bit-identical to the token scatter it replaced, at the static run count
+    ``max_write_runs`` gives the bucket — which each row respects."""
+    from repro.kvcache.paged import max_write_runs, run_starts, write_pages
+    block, Sq, segments, rows = WRITE_CASES[case]
+    L_, n_blocks, KV, hd, li = 3, 24, 2, 4, 1
+    rng = np.random.default_rng(len(case))
+    wblk, wslot, q_len = _write_rows(block, Sq, rows, n_blocks, rng)
+    n_runs = max_write_runs(Sq, block, segments)
+    runs = run_starts(wblk, wslot, np.arange(Sq)[None] < q_len[:, None])
+    assert runs.sum(axis=1).max() <= n_runs
+    if case in ("crosses_pages", "segments"):
+        assert runs.sum(axis=1).max() == n_runs
+    pages = jnp.asarray(rng.standard_normal((L_, n_blocks, KV, block, hd)),
+                        jnp.bfloat16)
+    vals = jnp.asarray(rng.standard_normal((len(rows), Sq, KV, hd)))
+    got = jax.jit(write_pages, static_argnums=6)(
+        pages, jnp.int32(li), jnp.asarray(wblk), jnp.asarray(wslot),
+        jnp.asarray(q_len), vals, n_runs)
+    # the oracle: the token scatter, padding tokens (scratch block) included
+    want = pages.at[li, wblk, :, wslot].set(vals.astype(pages.dtype))
+    live = np.arange(1, n_blocks)                  # block 0 is the scratch
+    assert np.array_equal(np.asarray(got)[:, live], np.asarray(want)[:, live])
+
+
+def test_run_paged_rows_asserts_rows_past_the_run_bound(serving_setup):
+    """A row that would write more pages than the step's static run count
+    trips the host assert before anything runs — it is never dropped."""
+    from repro.kvcache.paged import max_write_runs
+    from repro.serving.config import EngineConfig
+    from repro.serving.runtime import ContinuousRuntime
+    cfg, params, corpus, idx, _ = serving_setup
+    rt = ContinuousRuntime(cfg, params, corpus, idx, n_blocks=32,
+                           config=EngineConfig(top_k=2, attn="paged",
+                                               prefill_chunk=8))
+    n, bs = 8, rt.store.block_size
+    assert max_write_runs(n, bs, rt._row_segments) < n
+    wblk = np.arange(1, n + 1, dtype=np.int32)     # one page a token
+    wslot = np.full(n, bs - 1, np.int32)
+    T = 4
+    row = (None, np.zeros(n, np.int32), wblk, wslot, 0,
+           np.zeros(T, np.int32), np.zeros(T, np.int32),
+           np.zeros(T, np.int32), n)
+    with pytest.raises(AssertionError, match="writes 8 pages"):
+        rt._run_paged_rows([row])
+
+
 def test_paged_prefill_step_matches_dense_prefill(serving_setup):
     """paged_prefill_step == dense prefill logits BIT-FOR-BIT through the
     real model (rope, GQA, per-layer windows, scan), one-shot and split
