@@ -1,5 +1,5 @@
 """Weights from the seed, and the plain reference the served tokens are
-checked against.
+checked against: the default reference module of a configuration.
 
 The weights belong to the benchmark: ``served_params`` makes them on the
 device in one jitted call, in bfloat16 (the type they are served in), and
@@ -14,6 +14,27 @@ The control is the same reference computed in float8 (e4m3), the next
 precision below the configuration's bfloat16: every matrix rounded with
 one scale per output column, and every matmul's activation input, the
 attention's included, rounded with one scale per row.
+
+A configuration file may name another module under ``bench/`` as its
+``"reference"``; the harness (``run.load_reference``) imports it by path in
+place of this one.  Such a module provides what this one does:
+
+* ``widths(conf)``: an object with at least ``L, D, V, H, KV, hd``, the
+  per-layer attention ``windows`` (0 for full attention) and
+  ``matmul_flops``, the matmul FLOPs one token needs through every layer
+  (for experts, the routed ones and the router, never all of them);
+* ``program_fields(w)``: the program's ``ModelConfig`` keyword arguments
+  other than ``name`` and ``dtype``;
+* ``make_params(w, key)`` and ``served_params(w, seed)``: the weights in
+  the program's layout;
+* ``reference_logits(w, seed, seqs, rows, fp8=False)``;
+* optionally ``KERNELS``, further trace keys (kernel names or named-scope
+  substrings) whose device time the trace reduction credits, and
+  ``kernel_work(w, served, chunk, peak_flops, peak_bw)``, their work as
+  ``{key: work.Work}``.
+
+It imports the shared helpers (``base_key``, ``_fp8``, ``_rms``, ``_rope``,
+``attention``, ``final_hidden``, ...) from here, and nothing of the program.
 """
 from __future__ import annotations
 
@@ -45,6 +66,22 @@ class Widths:
     bias: bool
     tied: bool
 
+    @property
+    def windows(self) -> Tuple[int, ...]:
+        """Each layer's attention window (0: full attention)."""
+        return (self.window,) * self.L
+
+    @property
+    def attn_weights(self) -> int:
+        """Weights of one layer's q, k, v and output projections."""
+        D, Hd, Kd = self.D, self.H * self.hd, self.KV * self.hd
+        return D * Hd + 2 * D * Kd + Hd * D
+
+    @property
+    def matmul_flops(self) -> float:
+        """Dense matmul FLOPs of one token through every layer (no LM head)."""
+        return 2.0 * self.L * (self.attn_weights + 3 * self.D * self.F)
+
 
 def widths(conf: dict) -> Widths:
     """The decoder's sizes from a configuration file (Hugging Face keys)."""
@@ -58,6 +95,15 @@ def widths(conf: dict) -> Widths:
         hd=conf.get("head_dim") or D // H, theta=float(conf["rope_theta"]),
         eps=float(conf["rms_norm_eps"]), window=int(window),
         bias=bool(conf["qkv_bias"]), tied=bool(conf["tie_word_embeddings"]))
+
+
+def program_fields(w: Widths) -> dict:
+    """The program's ``ModelConfig`` fields for this decoder."""
+    return dict(
+        family="dense", n_layers=w.L, d_model=w.D, n_heads=w.H, n_kv_heads=w.KV,
+        d_ff=w.F, vocab_size=w.V, head_dim=w.hd, qkv_bias=w.bias, rope_theta=w.theta,
+        norm_eps=w.eps, sliding_window=w.window, global_every=0 if w.window else 1,
+        tie_embeddings=w.tied)
 
 
 def base_key(seed: int) -> jax.Array:
@@ -185,19 +231,20 @@ def _rope(x, pos, theta):
     return x * cos + jnp.concatenate([-x2, x1], -1) * sin
 
 
-@functools.partial(jax.jit, static_argnums=(0, 3))
-def _ref_layer(w: Widths, h, p, low: bool = False):
-    """One decoder layer over one sequence h: (S, D) float32.  With
+def mm(x, m, low: bool):
+    """A float32 matmul at HIGHEST; the control rounds its input to float8."""
+    return jnp.dot(_low(x, low), m, precision=HI)
+
+
+def attention(w, h, p, window: int, low: bool):
+    """h plus the attention sublayer of one layer over one sequence h: (S, D)
+    float32, keys limited to the last ``window`` positions (0: all).  With
     ``low`` (the control) every matmul, attention included, takes float8
     inputs."""
     S = h.shape[0]
     pos = jnp.arange(S)
-
-    def mm(x, m):
-        return jnp.dot(_low(x, low), m, precision=HI)
-
     x = _rms(h, p["ln1"], w.eps)
-    q, k, v = mm(x, p["wq"]), mm(x, p["wk"]), mm(x, p["wv"])
+    q, k, v = mm(x, p["wq"], low), mm(x, p["wk"], low), mm(x, p["wv"], low)
     if w.bias:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
     q = _low(_rope(q.reshape(S, w.H, w.hd), pos, w.theta), low)
@@ -211,24 +258,33 @@ def _ref_layer(w: Widths, h, p, low: bool = False):
         s = jnp.einsum("qgrd,kgd->grqk", qc, k, precision=HI) * w.hd ** -0.5
         qp, kp = lo + jnp.arange(Q_CHUNK)[:, None], pos[None, :]
         live = kp <= qp
-        if w.window:
-            live &= kp > qp - w.window
+        if window:
+            live &= kp > qp - window
         a = _low(jax.nn.softmax(jnp.where(live, s, -jnp.inf), axis=-1), low)
         return jnp.einsum("grqk,kgd->qgrd", a, v, precision=HI)
 
     o = jax.lax.map(block, (q, jnp.arange(0, S, Q_CHUNK)))
     o = o.reshape(S, w.H * w.hd)
-    h = h + mm(o, p["wo"])
+    return h + mm(o, p["wo"], low)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 3, 4))
+def _ref_layer(w: Widths, h, p, window: int, low: bool):
+    """One decoder layer over one sequence h: (S, D) float32."""
+    h = attention(w, h, p, window, low)
     x = _rms(h, p["ln2"], w.eps)
-    f = jax.nn.silu(mm(x, p["wg"])) * mm(x, p["wu"])
-    return h + mm(f, p["wd"])
+    f = jax.nn.silu(mm(x, p["wg"], low)) * mm(x, p["wu"], low)
+    return h + mm(f, p["wd"], low)
 
 
-def final_hidden(w: Widths, seed: int, seqs: Sequence[np.ndarray], fp8: bool = False):
+def final_hidden(w, seed: int, seqs: Sequence[np.ndarray], fp8: bool = False,
+                 weights=_ref_layer_weights, layer=_ref_layer):
     """(final-normed hidden rows per sequence, padded at the end to a
     multiple of PAD, which no earlier row can see; the LM head).  Layer by
-    layer: each layer's weights are made once from the seed and applied to
-    every sequence."""
+    layer: each layer's weights are made once from the seed
+    (``weights(w, base, i, fp8)``) and applied to every sequence
+    (``layer(w, h, p, window, fp8)``).  A reference module with another
+    layer passes its own two functions."""
     base = base_key(seed)
     embed, final_norm, head = _ref_global_weights(w, base, fp8)
     hs = []
@@ -238,20 +294,21 @@ def final_hidden(w: Widths, seed: int, seqs: Sequence[np.ndarray], fp8: bool = F
         ids[:len(t)] = t
         hs.append(embed[jnp.asarray(ids)])
     del embed
-    for layer in range(w.L):
-        p = _ref_layer_weights(w, base, layer, fp8)
-        hs = [_ref_layer(w, h, p, fp8) for h in hs]
+    for i, window in enumerate(w.windows):
+        p = weights(w, base, i, fp8)
+        hs = [layer(w, h, p, window, fp8) for h in hs]
         del p
     return [_rms(h, final_norm, w.eps) for h in hs], head
 
 
-def reference_logits(w: Widths, seed: int, seqs: Sequence[np.ndarray],
-                     rows: Sequence[np.ndarray], fp8: bool = False) -> List[np.ndarray]:
+def reference_logits(w, seed: int, seqs: Sequence[np.ndarray],
+                     rows: Sequence[np.ndarray], fp8: bool = False,
+                     weights=_ref_layer_weights, layer=_ref_layer) -> List[np.ndarray]:
     """Logits of the plain model at the given rows of each token sequence:
     one (len(rows[i]), V) float32 array per sequence.  With ``fp8``, the
     control's: the same model computed in float8, the head's input rows
-    rounded too."""
-    hs, head = final_hidden(w, seed, seqs, fp8)
+    rounded too.  ``weights`` and ``layer`` as in ``final_hidden``."""
+    hs, head = final_hidden(w, seed, seqs, fp8, weights, layer)
     return [np.asarray(jnp.dot(_low(h[jnp.asarray(r)], fp8), head, precision=HI))
             for h, r in zip(hs, rows)]
 

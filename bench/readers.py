@@ -29,8 +29,10 @@ def tok_s(ctx):
 
 def roofline(ctx, kernel):
     """Least time the chip could take for the kernel's work, over the
-    kernel's device time, in percent."""
-    if ctx.trace is None:
+    kernel's device time, in percent.  ``kernel`` is any trace key: the
+    paged pair's work comes from ``work.count``, another key's from its
+    reference module's ``kernel_work``."""
+    if ctx.trace is None or kernel not in ctx.work:
         return None
     t = ctx.trace["kernel_s"].get(kernel, 0.0)
     need = ctx.work[kernel].min_seconds

@@ -2,10 +2,12 @@
 
     JAX_PLATFORMS=cpu python3 bench/rehearse.py
 
-For every configuration in BENCHMARK.json, at its mixes' page size: both
-paged kernels alone, the weight-making call, and (``--steps``, the default)
-the whole paged prefill step for every row bucket and the decode step, with
-the KV pool the configuration sizes.  Prints the compiler's memory analysis
+For every configuration in BENCHMARK.json, through its reference module
+(``run.load_reference``) and at its mixes' page size: both paged kernels
+alone, the module's weight-making call, and (unless ``--no-steps``) the
+whole paged prefill step for every row bucket and the decode step of the
+program the module's fields describe, with the KV pool the configuration
+sizes.  Prints the compiler's memory analysis
 of each; nothing runs, so nothing here is a measurement.
 """
 from __future__ import annotations
@@ -25,7 +27,6 @@ import jax.numpy as jnp  # noqa: E402
 from jax.experimental import topologies  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-import model  # noqa: E402
 import run  # noqa: E402
 
 
@@ -33,18 +34,19 @@ def gb(n: float) -> str:
     return f"{n / 1e9:.3f} GB"
 
 
-def report(name: str, compiled) -> None:
+def report(name: str, compiled, keys) -> None:
     m = compiled.memory_analysis()
-    kernels = [k for k in run.KERNELS if k in compiled.as_text()]
+    kernels = [k for k in keys if k in compiled.as_text()]
     print(f"{name}: arguments {gb(m.argument_size_in_bytes)}, outputs "
           f"{gb(m.output_size_in_bytes)}, temporaries {gb(m.temp_size_in_bytes)}, "
           f"aliased {gb(m.alias_size_in_bytes)}; kernels {kernels}", flush=True)
 
 
-def main() -> None:
+def main(argv=None, root: str = ROOT) -> None:
+    """Rehearse the configurations of the checkout at ``root``."""
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--no-steps", action="store_true", help="kernels and weights only")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     jax.config.update("jax_enable_compilation_cache", False)
     from repro.kernels import paged_attention, paged_prefill
     from repro.models import model as M
@@ -55,12 +57,14 @@ def main() -> None:
     def sds(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    bench = run.load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    bench = run.load_json(os.path.join(root, "BENCHMARK.json"))
     for c in bench["configs"]:
-        conf = run.load_json(os.path.join(ROOT, c["file"]))
-        w = model.widths(conf)
+        conf = run.load_json(os.path.join(root, c["file"]))
+        ref = run.load_reference(root, conf)
+        keys = run.trace_keys(ref)
+        w = ref.widths(conf)
         mixes = {wl["traffic"] for wl in bench["workloads"] if wl["config"] == c["name"]}
-        mix = run.load_json(os.path.join(BENCH, "traffic", f"{sorted(mixes)[0]}.json"))
+        mix = run.load_json(os.path.join(root, "bench", "traffic", f"{sorted(mixes)[0]}.json"))
         eng = mix["engine"]
         page, B = eng["block_size"], eng["max_batch"]
         n_blocks = conf["serving"]["pool_bytes"] // (page * run.kv_bytes_per_token(w))
@@ -73,22 +77,22 @@ def main() -> None:
         q = sds((B, w.H, w.hd), jnp.bfloat16)
         dec = jax.jit(functools.partial(paged_attention.paged_decode_attention))
         report(f"paged_decode B={B}", dec.lower(q, pool, pool, sds((B, T)), sds((B, T)),
-                                                  sds((B, T)), sds((B,)), 0, 0).compile())
+                                                  sds((B, T)), sds((B,)), 0, 0).compile(), keys)
         rows = sorted(run.expected_prefill_rows(mix))
         for sq in rows:
             q = sds((1, w.H, sq, w.hd), jnp.bfloat16)
             pre = jax.jit(paged_prefill.paged_prefill_attention)
             report(f"paged_prefill 1x{sq}", pre.lower(
                 q, pool, pool, sds((1, T)), sds((1, T)), sds((1, T)), sds((1,)),
-                sds((1,)), 0, 0).compile())
+                sds((1,)), 0, 0).compile(), keys)
         # weights
         base = jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=one)
-        make = jax.jit(functools.partial(model.make_params, w))
-        report("weights", make.lower(base).compile())
+        make = jax.jit(functools.partial(ref.make_params, w))
+        report("weights", make.lower(base).compile(), keys)
         if args.no_steps:
             continue
-        cfg = run.program_config(conf, w)
-        params = jax.eval_shape(functools.partial(model.make_params, w), jax.random.key(0))
+        cfg = run.program_config(conf, ref, w)
+        params = jax.eval_shape(functools.partial(ref.make_params, w), jax.random.key(0))
         params = jax.tree.map(lambda s: sds(s.shape, s.dtype), params)
         pre_step = jax.jit(
             lambda p, toks, tb, cn, sts, qs, ql, wb, ws, kp, vp:
@@ -98,7 +102,7 @@ def main() -> None:
         for sq in rows:
             report(f"prefill step 1x{sq}", pre_step.lower(
                 params, sds((1, sq)), sds((1, T)), sds((1, T)), sds((1, T)), sds((1,)),
-                sds((1,)), sds((1, sq)), sds((1, sq)), pool, pool).compile())
+                sds((1,)), sds((1, sq)), sds((1, sq)), pool, pool).compile(), keys)
         dec_step = jax.jit(
             lambda p, toks, tb, cn, sts, pos, wb, ws, kp, vp:
             M.paged_decode_step(cfg, p, toks, kp, vp, tb, cn, sts, wb, ws, pos,
@@ -106,7 +110,7 @@ def main() -> None:
             donate_argnums=(8, 9))
         report(f"decode step B={B}", dec_step.lower(
             params, sds((B, 1)), sds((B, T)), sds((B, T)), sds((B, T)), sds((B,)),
-            sds((B,)), sds((B,)), pool, pool).compile())
+            sds((B,)), sds((B,)), pool, pool).compile(), keys)
 
 
 if __name__ == "__main__":

@@ -6,7 +6,11 @@ The cell (``BENCHMARK.json``'s ``workloads``) names a configuration, whose
 file under ``bench/configs`` holds the model's sizes and its serving
 deployment, and a traffic mix, ``bench/traffic/<traffic>.json``, which
 holds the corpus, the request stream, the loop and the serving settings.
-Each metric is read by ``bench/metrics/<metric>.py``.
+The configuration's reference module, ``bench/<"reference">`` or
+``bench/model.py`` where the file names none, gives its widths, its
+weights, the program's model fields, the plain reference and any further
+trace keys with their work (``model.py`` says what it provides).  Each
+metric is read by ``bench/metrics/<metric>.py``.
 
 Set-up makes the weights on the device from the seed, builds the corpus,
 the index and the continuous runtime through its public constructor, warms
@@ -19,7 +23,7 @@ cell's own traffic.  The window then drives ``ContinuousRuntime.serve``:
   a whole.
 
 After the window the served tokens of a sample of requests are compared
-with the plain float32 reference (``model.py``).  With ``--trace 1`` the
+with the plain float32 reference.  With ``--trace 1`` the
 window runs under the profiler and the per-layer metrics are read from the
 trace, the program's counters and the work counts (``work.py``).
 
@@ -64,7 +68,7 @@ sys.path[:0] = [os.path.join(ROOT, "src"), BENCH, os.path.join(BENCH, "traffic")
 import numpy as np  # noqa: E402
 
 TRACE_DIR = os.path.join(ROOT, ".bench_trace")
-KERNELS = ("paged_prefill", "paged_decode")
+KERNELS = ("paged_prefill", "paged_decode")      # trace keys of every configuration
 
 
 def log(msg: str) -> None:
@@ -108,12 +112,36 @@ def load_cell(root: str, name: str, trace: bool) -> Cell:
     return Cell(name, int(w["chips"]), conf, mix, names)
 
 
-def load_reader(root: str, metric: str):
-    path = os.path.join(root, "bench", "metrics", f"{metric}.py")
-    spec = importlib.util.spec_from_file_location(f"metric_{metric}", path)
+def _import(path: str, name: str):
+    """The module at ``path``, registered as ``name`` (a dataclass needs its
+    module in ``sys.modules`` while the class is made)."""
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(root: str, metric: str):
+    return _import(os.path.join(root, "bench", "metrics", f"{metric}.py"),
+                   f"metric_{metric}").read
+
+
+def load_reference(root: str, conf: dict):
+    """The configuration's reference module: the file under ``bench/`` that
+    its ``"reference"`` names, ``model.py`` where it names none."""
+    name = conf.get("reference", "model.py")
+    rel = os.path.normpath(name)
+    if os.path.isabs(rel) or rel.startswith("..") or not rel.endswith(".py"):
+        raise SystemExit(f"reference {name!r} is not a .py file under bench/")
+    return _import(os.path.join(root, "bench", rel),
+                   "reference_" + rel[:-3].replace(os.sep, "_").replace(".", "_"))
+
+
+def trace_keys(ref) -> tuple:
+    """The keys whose device time the trace reduction credits: the paged
+    kernels and the reference module's own ``KERNELS``."""
+    return KERNELS + tuple(getattr(ref, "KERNELS", ()))
 
 
 class CompileCounter:
@@ -163,14 +191,10 @@ class Context:
     peak: dict
 
 
-def program_config(conf: dict, w):
+def program_config(conf: dict, ref, w):
     from repro.models.config import ModelConfig
-    return ModelConfig(
-        name=conf["name"], family="dense", n_layers=w.L, d_model=w.D,
-        n_heads=w.H, n_kv_heads=w.KV, d_ff=w.F, vocab_size=w.V,
-        head_dim=w.hd, qkv_bias=w.bias, rope_theta=w.theta, norm_eps=w.eps,
-        sliding_window=w.window, global_every=0 if w.window else 1,
-        tie_embeddings=w.tied, dtype=conf["torch_dtype"])
+    return ModelConfig(name=conf["name"], dtype=conf["torch_dtype"],
+                       **ref.program_fields(w))
 
 
 def kv_bytes_per_token(w) -> int:
@@ -196,8 +220,7 @@ def expected_prefill_rows(mix: dict) -> set:
 class Run:
     """One run of a cell: set-up, window, metrics, correctness."""
 
-    def __init__(self, cell: Cell, seed: int, seconds: float, peak: dict, jax):
-        import model
+    def __init__(self, cell: Cell, ref, seed: int, seconds: float, peak: dict, jax):
         from generate import Traffic, make_passages
         from repro.retrieval.corpus import Corpus
         from repro.retrieval.vectordb import IVFIndex
@@ -208,8 +231,8 @@ class Run:
         self.jax = jax
         conf, mix = cell.conf, cell.mix
         eng = mix["engine"]
-        self.w = w = model.widths(conf)
-        self.params = model.served_params(w, seed)
+        self.w = w = ref.widths(conf)
+        self.params = ref.served_params(w, seed)
         jax.block_until_ready(self.params)
         self.passages = make_passages(mix, w.V, seed)
         self.traffic = Traffic(mix, self.passages, w.V, seed)
@@ -222,7 +245,7 @@ class Run:
         block = eng["block_size"]
         n_blocks = tiers["pool_bytes"] // (block * kv_bytes_per_token(w))
         self.rt = ContinuousRuntime(
-            program_config(conf, w), self.params, corpus, index,
+            program_config(conf, ref, w), self.params, corpus, index,
             config=EngineConfig(
                 gpu_cache_bytes=tiers["device_tier_bytes"],
                 host_cache_bytes=tiers["host_tier_bytes"],
@@ -380,21 +403,23 @@ def passes(checks: dict) -> bool:
     return all(c["value"] <= c["limit"] for c in checks.values())
 
 
-def compare(w, seed: int, sample: List[ServedRequest], control: bool = False) -> dict:
+def compare(reference, w, seed: int, sample: List[ServedRequest],
+            control: bool = False) -> dict:
     """The program's served tokens and first-token logits judged against the
-    float32 reference; with ``control``, the float8 control judged the same
-    way in the program's place: its first choice at every row where the
-    program chose a token, and its logits at the first."""
+    float32 reference (the configuration's ``reference`` module); with
+    ``control``, the float8 control judged the same way in the program's
+    place: its first choice at every row where the program chose a token,
+    and its logits at the first."""
     import model
     seqs, rows = zip(*(model.served_positions(r.prompt, r.tokens) for r in sample))
-    ref = model.reference_logits(w, seed, seqs, rows)
+    ref = reference.reference_logits(w, seed, seqs, rows)
     out = {"program": judge(ref, [r.tokens for r in sample],
                             [r.first_logits for r in sample]),
            "tokens": sum(len(r.tokens) for r in sample)}
     out["tokens_off_reference"] = int(sum(
         (model.logit_gaps(g, r.tokens) > 0).sum() for g, r in zip(ref, sample)))
     if control:
-        low = model.reference_logits(w, seed, seqs, rows, fp8=True)
+        low = reference.reference_logits(w, seed, seqs, rows, fp8=True)
         out["control"] = judge(ref, [lg.argmax(1) for lg in low], [lg[0] for lg in low])
     return out
 
@@ -428,8 +453,9 @@ def execute(root: str, workload: str, seed: int, seconds: float, trace: bool,
     kind = devices[0].device_kind
     if kind not in table:
         raise SystemExit(f"no peaks for device kind {kind!r} in peaks.json")
+    ref = load_reference(root, cell.conf)
     counter = CompileCounter(jax)
-    run = Run(cell, seed, seconds, table[kind], jax)
+    run = Run(cell, ref, seed, seconds, table[kind], jax)
     run.setup()
     setup_s = time.monotonic() - T_PROCESS
     log(f"setup: {setup_s:.3f} s, {counter.compile_s:.3f} s of it compiling")
@@ -451,14 +477,16 @@ def execute(root: str, workload: str, seed: int, seconds: float, trace: bool,
         import trace_reduce as trace_mod
         import work as work_mod
         dev_ops, host = trace_mod.read(trace_mod.latest_xplane(TRACE_DIR))
-        tr = trace_mod.reduce(dev_ops, host, KERNELS)
+        tr = trace_mod.reduce(dev_ops, host, trace_keys(ref))
         log(f"trace: busy {tr['busy_s']:.6f} s of {tr['window_s']:.6f} s; kernels "
             + ", ".join(f"{k} {v:.6f} s" for k, v in tr["kernel_s"].items()))
-        work = work_mod.count(
-            run.w, [work_mod.Served(r.segments, r.alpha, max(0, len(r.tokens) - 1))
-                    for r in served],
-            cell.mix["engine"]["prefill_chunk"], run.peak["bf16_flops"],
-            run.peak["hbm_bytes_per_s"])
+        needs = [work_mod.Served(r.segments, r.alpha, max(0, len(r.tokens) - 1))
+                 for r in served]
+        args = (run.w, needs, cell.mix["engine"]["prefill_chunk"],
+                run.peak["bf16_flops"], run.peak["hbm_bytes_per_s"])
+        work = work_mod.count(*args)
+        if hasattr(ref, "kernel_work"):
+            work.update(ref.kernel_work(*args))
     dev = device_info(devices, cell.chips, tr)
     log(f"device: peak_bytes_in_use {dev['memory_peak_bytes']}")
     ctx = Context(setup_s, run.window_s, run.latencies, served, tr, work, run.peak)
@@ -473,7 +501,7 @@ def execute(root: str, workload: str, seed: int, seconds: float, trace: bool,
     run.free_program()
     t0 = time.perf_counter()
     limits = {k: float(v) for k, v in cell.conf["check"].items()}
-    cmp = compare(w, seed, sample, control)
+    cmp = compare(ref, w, seed, sample, control)
     log(f"reference: {len(sample)} requests, {cmp['tokens']} served tokens, "
         f"{cmp['tokens_off_reference']} not the reference's first choice, "
         f"{time.perf_counter() - t0:.3f} s")

@@ -62,7 +62,7 @@ def test_reference_agrees_with_the_program_forward():
     """The program's dense forward (bfloat16) and the reference pick the
     same tokens, with logits within bfloat16 rounding."""
     from repro.models import model as M
-    cfg = run.program_config(tiny.TINY_CONF, W)
+    cfg = run.program_config(tiny.TINY_CONF, model, W)
     params = model.served_params(W, SEED)
     toks = np.random.default_rng(0).integers(0, W.V, 150).astype(np.int32)
     prog = np.asarray(M.forward(cfg, params, {"tokens": jnp.asarray(toks)[None]}))[0]
@@ -77,7 +77,7 @@ def test_control_lies_further_from_the_reference():
     """At this size too the float8 control's logits lie further from the
     reference's than the program's do."""
     from repro.models import model as M
-    cfg = run.program_config(tiny.TINY_CONF, W)
+    cfg = run.program_config(tiny.TINY_CONF, model, W)
     params = model.served_params(W, SEED)
     rng = np.random.default_rng(1)
     seqs = [rng.integers(0, W.V, n).astype(np.int32) for n in (90, 140, 200)]
@@ -91,10 +91,12 @@ def test_control_lies_further_from_the_reference():
     assert ctrl_err > 3 * prog_err
 
 
-def test_control_in_the_programs_place_is_not_correct(tmp_path):
+@pytest.mark.parametrize("conf", [tiny.TINY_CONF, tiny.TINY_MOE_CONF], ids=["dense", "moe"])
+def test_control_in_the_programs_place_is_not_correct(tmp_path, conf):
     """A run of a cell whose program is correct: the float8 control, judged
-    by the same comparison at the same rows, is not."""
-    root = tiny.make_root(str(tmp_path / "co"), {"unique.single": UNIQUE_SINGLE})
+    by the same comparison at the same rows, is not.  The control is the
+    float8 reference of the configuration's own module."""
+    root = tiny.make_root(str(tmp_path / "co"), {"unique.single": UNIQUE_SINGLE}, conf)
     res = execute(root, "tiny.unique.single", control=True)
     assert res["correct"]
     assert res["control"]["correct"] is False

@@ -150,7 +150,7 @@ def test_count_adds_pieces_and_decode():
     assert out["paged_prefill"].flops == f
     d = work.decode_attention(W, 72)[0] + work.decode_attention(W, 73)[0]
     assert out["paged_decode"].flops == d
-    mm = work.matmul_flops_per_token(W)
+    mm = W.matmul_flops
     assert out["model_flops"] == f + d + 56 * mm + 2 * mm + 3 * work.head_flops(W)
 
 
@@ -163,7 +163,7 @@ def test_matmul_flops_match_the_compiled_program():
     from repro.models import model as M
     conf = dict(tiny.TINY_CONF, sliding_window=0)
     w = model.widths(conf)
-    cfg = run.program_config(conf, w)
+    cfg = run.program_config(conf, model, w)
     params = jax.eval_shape(lambda k: model.make_params(w, k), jax.random.key(0))
     B, S = 3, 64
     cache = M.init_decode_cache(cfg, B, S)
@@ -172,7 +172,7 @@ def test_matmul_flops_match_the_compiled_program():
                     jnp.ones((B,), jnp.int32)).compile().as_text()
     hlo = hlo_analysis.analyze(text).flops
     attn = w.L * 4 * w.H * w.hd * S
-    want = B * (work.matmul_flops_per_token(w) + work.head_flops(w) + attn)
+    want = B * (w.matmul_flops + work.head_flops(w) + attn)
     assert hlo == pytest.approx(want, rel=1e-6)
 
 

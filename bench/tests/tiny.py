@@ -6,7 +6,8 @@ import json
 import os
 import shutil
 
-BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
 
 TINY_CONF = {
@@ -19,6 +20,12 @@ TINY_CONF = {
                 "host_tier_bytes": 100000},
     "check": {"logit_gap": 0.05, "logit_linf": 0.05},
 }
+
+# Four experts of which each token takes two, the first layer windowed
+# and the second full; the reference module is this directory's moe_ref.py.
+TINY_MOE_CONF = dict(
+    TINY_CONF, name="tiny-moe", reference="moe_ref.py", num_local_experts=4,
+    num_experts_per_tok=2, layer_types=["sliding_attention", "full_attention"])
 
 TINY_MIX = {
     "loop": "batch", "passages": 60, "median_tokens": 48, "sigma": 0.4,
@@ -45,10 +52,15 @@ TOK_S = {"name": "tok_s", "unit": "tok/s", "better": "higher", "bound": 0.1,
 
 def make_root(tmp: str, mixes: dict, conf: dict = TINY_CONF) -> str:
     """A checkout under ``tmp`` whose BENCHMARK.json has one tiny cell per
-    mix (named ``tiny.<mix>``), with the real metric readers.  The batch
-    cells' throughput metric is added as a later cell would add it: a
-    reader file and an entry."""
+    mix (named ``tiny.<mix>``), with the real metric readers and the
+    default reference module, and the configuration's own reference module
+    where it names one in this directory.  The batch cells' throughput
+    metric is added as a later cell would add it: a reader file and an
+    entry."""
     shutil.copytree(os.path.join(BENCH, "metrics"), os.path.join(tmp, "bench", "metrics"))
+    shutil.copy(os.path.join(BENCH, "model.py"), os.path.join(tmp, "bench"))
+    if "reference" in conf:
+        shutil.copy(os.path.join(HERE, conf["reference"]), os.path.join(tmp, "bench"))
     bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
     dump(os.path.join(tmp, "bench", "configs", "tiny.json"), conf)
     bench["configs"] = [{"name": "tiny", "source": "test", "file": "bench/configs/tiny.json",
@@ -71,3 +83,19 @@ def make_root(tmp: str, mixes: dict, conf: dict = TINY_CONF) -> str:
                     "from readers import tok_s as read  # noqa: F401\n")
     dump(os.path.join(tmp, "BENCHMARK.json"), bench)
     return tmp
+
+
+MLP_ROOFLINE = {"name": "mlp_roofline.single", "unit": "%", "better": "higher",
+                "source": "device_trace", "layer": "model step", "moves": "ttft_p95_ms"}
+
+
+def add_metric(root: str, entry: dict, reader: str) -> None:
+    """A per-layer metric added as a later PR adds one: a reader file and
+    an entry, for every cell."""
+    path = os.path.join(root, "BENCHMARK.json")
+    bench = json.load(open(path))
+    cells = [w["name"] for w in bench["workloads"]]
+    bench["per_layer"].append(dict(entry, workloads=cells))
+    dump(path, bench)
+    with open(os.path.join(root, "bench", "metrics", f"{entry['name']}.py"), "w") as f:
+        f.write(reader)

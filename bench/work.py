@@ -3,12 +3,15 @@
 Counts follow what a request needs, not what the program happens to run:
 padding rows, unused table entries and pages past a query's position are
 not counted, and a sliding window caps the keys a query sees.  Shapes come
-from the configuration's widths (``model.Widths``); token positions come
-from each request's prompt, its cached prefix (alpha) and the prefill
-pieces the scheduler splits its uncached part into.
+from the widths of the configuration's reference module (``widths(conf)``:
+``H``, ``KV``, ``hd``, ``D``, ``V``, each layer's ``windows`` and the
+token's ``matmul_flops``); token positions come from each request's
+prompt, its cached prefix (alpha) and the prefill pieces the scheduler
+splits its uncached part into.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Iterable, List, Sequence, Tuple
 
@@ -59,32 +62,38 @@ def _sum_keys(lo: int, hi: int, window: int) -> int:
     return s + (hi - cut) * window
 
 
+def _by_window(w):
+    """(window, number of layers with it), in order of first appearance."""
+    return collections.Counter(w.windows).items()
+
+
 def prefill_attention(w, q_start: int, n: int) -> Tuple[float, float]:
     """(flops, bytes) of one prefill piece's attention, all layers: n query
     rows at positions q_start.. q_start+n-1 against their visible keys."""
-    flops = 4.0 * w.H * w.hd * _sum_keys(q_start, q_start + n, w.window)
+    flops = nbytes = 0.0
     last = q_start + n - 1
-    first_key = max(0, last - w.window + 1) if w.window else 0
-    kv_tokens = last + 1 - first_key
-    qo = 2 * n * w.H * w.hd * KV_BYTES
-    kv = 2 * kv_tokens * w.KV * w.hd * KV_BYTES
-    return w.L * flops, w.L * float(qo + kv)
+    for window, layers in _by_window(w):
+        f = 4.0 * w.H * w.hd * _sum_keys(q_start, q_start + n, window)
+        first_key = max(0, last - window + 1) if window else 0
+        kv_tokens = last + 1 - first_key
+        qo = 2 * n * w.H * w.hd * KV_BYTES
+        kv = 2 * kv_tokens * w.KV * w.hd * KV_BYTES
+        flops += layers * f
+        nbytes += layers * float(qo + kv)
+    return flops, nbytes
 
 
 def decode_attention(w, pos: int) -> Tuple[float, float]:
     """(flops, bytes) of one decode token's attention at absolute position
     pos, all layers."""
-    keys = _keys(pos, w.window)
-    flops = 4.0 * w.H * w.hd * keys
-    nbytes = 2 * keys * w.KV * w.hd * KV_BYTES + 2 * w.H * w.hd * KV_BYTES
-    return w.L * flops, w.L * float(nbytes)
-
-
-def matmul_flops_per_token(w) -> float:
-    """Dense matmul FLOPs of one token through every layer (no LM head)."""
-    attn = w.D * w.H * w.hd + 2 * w.D * w.KV * w.hd + w.H * w.hd * w.D
-    mlp = 3 * w.D * w.F
-    return 2.0 * w.L * (attn + mlp)
+    flops = nbytes = 0.0
+    for window, layers in _by_window(w):
+        keys = _keys(pos, window)
+        f = 4.0 * w.H * w.hd * keys
+        b = 2 * keys * w.KV * w.hd * KV_BYTES + 2 * w.H * w.hd * KV_BYTES
+        flops += layers * f
+        nbytes += layers * float(b)
+    return flops, nbytes
 
 
 def head_flops(w) -> float:
@@ -113,12 +122,12 @@ def count(w, served: Iterable[Served], chunk: int, peak_flops: float,
         for n in ps:
             f, b = prefill_attention(w, pos, n)
             pre.add(f, b, peak_flops, peak_bw)
-            model += f + n * matmul_flops_per_token(w)
+            model += f + n * w.matmul_flops
             pos += n
         model += head_flops(w) if ps else 0.0
         for _ in range(r.decoded):
             f, b = decode_attention(w, pos)
             dec.add(f, b, peak_flops, peak_bw)
-            model += f + matmul_flops_per_token(w) + head_flops(w)
+            model += f + w.matmul_flops + head_flops(w)
             pos += 1
     return {"paged_prefill": pre, "paged_decode": dec, "model_flops": model}
